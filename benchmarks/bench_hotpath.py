@@ -387,7 +387,9 @@ def measure(smoke: bool = False) -> dict:
     jax may already be initialized in this process) and parse its report."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=16",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", ""),
+               # a fake-device rehearsal: never the chip, which this
+               # process may already hold
+               JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(REPO, "src"), REPO,
                     os.environ.get("PYTHONPATH", "")]))

@@ -137,7 +137,9 @@ def measure(smoke: bool = False) -> dict:
     """Spawn the measurement subprocess (needs its own XLA device count)."""
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", ""),
+               # a fake-device rehearsal: never the chip, which this
+               # process may already hold
+               JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(REPO, "src"), REPO,
                     os.environ.get("PYTHONPATH", "")]))

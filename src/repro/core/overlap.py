@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.shapes import layer_stages, stage_boundaries
 from repro.core import nonuniform as nu
 from repro.core import ntp_train as nt

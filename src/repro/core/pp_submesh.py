@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import nonuniform as nu
 from repro.core import ntp_train as nt
 from repro.optim.base import Optimizer, sgd
@@ -108,9 +108,10 @@ def stack_staged_params(cfg: nt.NTPModelConfig, packed, staged: nu.StagedPlan):
     exactly its own layers. Replicated layer leaves (ln1/ln2/router) become
     ``(pp, l_max, ...)`` kept REPLICATED (``P()``): the step body
     dynamic-indexes its own stage's row by ``axis_index("stage")``. (They are
-    small, and sharding them ``P("stage")`` trips a jax 0.4.x partitioner bug
-    when this stacking is traced inside the same jit as the shard_map — the
-    stage slices arrive corrupted; the 4-axis unit spec is unaffected.)
+    small, and sharding them ``P("stage")`` tripped a partitioner bug on jax
+    0.4.x when this stacking was traced inside the same jit as the shard_map
+    — the stage slices arrived corrupted; the 4-axis unit spec is
+    unaffected. Not re-checked on the installed jax 0.9.)
     Pure jnp reshape/pad/stack — differentiable, so the step's grads flow
     straight back to the packed tree this was built from (pad-slot and
     pad-layer cotangents are dropped by the transpose of the pad)."""

@@ -17,10 +17,21 @@ whose tables index unit ROWS only, so the concatenated payload is opaque to
 the Algorithm-1 tables and the fused buffer reshards with the per-leaf
 tables unchanged. Offsets and widths are static (they come from the leaf
 shapes), so both kernels are straight-line copies with no index traffic.
+
+Tiling: the grid runs over column tiles of the bucket, ``t`` lanes wide
+(a 128-multiple that divides every leaf width), so no tile straddles two
+leaves and no VMEM block grows with the bucket. Every operand holds a
+double-buffered block, so ``t`` is sized for all of them together to stay
+inside ``_VMEM_BYTES`` (below the 16 MiB default scoped-VMEM limit). Leaf
+``i`` owns tiles ``[start_i, start_i + n_i)``; its block index is clamped
+into that range, so outside it the pipeline neither refetches its input nor
+writes back its output. Widths that are not 128-multiples (small replicated
+leaves) fall back to one whole-array block.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence, Tuple
 
 import jax
@@ -29,9 +40,57 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.mode import pallas_interpret
 
+_LANES = 128
+_VMEM_BYTES = 12 << 20
 
-def _pack_kernel(*refs):
-    """refs = (*leaf_refs, out_ref): copy each leaf into its column slice."""
+
+def _col_tile(rows: int, widths: Tuple[int, ...], itemsize: int):
+    """Column tile (lanes) shared by every leaf, or None when the widths
+    admit no 128-aligned common tile."""
+    g = math.gcd(*widths)
+    if g % _LANES:
+        return None
+    block_bytes = _VMEM_BYTES // (2 * (len(widths) + 1))
+    t = _LANES * max(1, min(g // _LANES,
+                            block_bytes // (rows * itemsize * _LANES)))
+    while g % t:
+        t -= _LANES
+    return t
+
+
+def _tile_ranges(widths, t):
+    """(start tile, tile count) of each leaf in the bucket."""
+    out, start = [], 0
+    for w in widths:
+        out.append((start, w // t))
+        start += w // t
+    return out
+
+
+def _leaf_block(rows, t, start, count):
+    return pl.BlockSpec(
+        (rows, t),
+        lambda j: (0, jnp.minimum(jnp.maximum(j - start, 0), count - 1)))
+
+
+def _pack_kernel(*refs, ranges):
+    """refs = (*leaf_refs, out_ref): copy the leaf owning this tile."""
+    out_ref, j = refs[-1], pl.program_id(0)
+    for ref, (start, count) in zip(refs[:-1], ranges):
+        @pl.when((j >= start) & (j < start + count))
+        def _copy(ref=ref):
+            out_ref[...] = ref[...]
+
+
+def _unpack_kernel(flat_ref, *out_refs, ranges):
+    j = pl.program_id(0)
+    for ref, (start, count) in zip(out_refs, ranges):
+        @pl.when((j >= start) & (j < start + count))
+        def _copy(ref=ref):
+            ref[...] = flat_ref[...]
+
+
+def _pack_whole(*refs):
     out_ref = refs[-1]
     off = 0
     for ref in refs[:-1]:
@@ -40,7 +99,7 @@ def _pack_kernel(*refs):
         off += w
 
 
-def _unpack_kernel(flat_ref, *out_refs):
+def _unpack_whole(flat_ref, *out_refs):
     off = 0
     for ref in out_refs:
         w = ref.shape[1]
@@ -68,14 +127,28 @@ def bucket_pack(leaves: Sequence, *, interpret: bool | None = None):
             )
     if len(leaves) == 1:
         return leaves[0]
-    total = sum(x.shape[1] for x in leaves)
+    widths = tuple(x.shape[1] for x in leaves)
+    total = sum(widths)
     interpret = pallas_interpret(interpret)
+    out_shape = jax.ShapeDtypeStruct((rows, total), dtype)
+    t = _col_tile(rows, widths, jnp.dtype(dtype).itemsize)
+    if t is None:
+        return pl.pallas_call(
+            _pack_whole,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(x.shape, lambda i: (0, 0))
+                      for x in leaves],
+            out_specs=pl.BlockSpec((rows, total), lambda i: (0, 0)),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(*leaves)
+    ranges = _tile_ranges(widths, t)
     return pl.pallas_call(
-        _pack_kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec(x.shape, lambda i: (0, 0)) for x in leaves],
-        out_specs=pl.BlockSpec((rows, total), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, total), dtype),
+        functools.partial(_pack_kernel, ranges=ranges),
+        grid=(total // t,),
+        in_specs=[_leaf_block(rows, t, *r) for r in ranges],
+        out_specs=pl.BlockSpec((rows, t), lambda j: (0, j)),
+        out_shape=out_shape,
         interpret=interpret,
     )(*leaves)
 
@@ -92,13 +165,25 @@ def bucket_unpack(flat, widths: Tuple[int, ...], *,
     if len(widths) == 1:
         return (flat,)
     interpret = pallas_interpret(interpret)
+    out_shape = [jax.ShapeDtypeStruct((rows, w), flat.dtype) for w in widths]
+    t = _col_tile(rows, widths, flat.dtype.itemsize)
+    if t is None:
+        return tuple(pl.pallas_call(
+            _unpack_whole,
+            grid=(1,),
+            in_specs=[pl.BlockSpec((rows, total), lambda i: (0, 0))],
+            out_specs=[pl.BlockSpec((rows, w), lambda i: (0, 0))
+                       for w in widths],
+            out_shape=out_shape,
+            interpret=interpret,
+        )(flat))
+    ranges = _tile_ranges(widths, t)
     return tuple(pl.pallas_call(
-        _unpack_kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((rows, total), lambda i: (0, 0))],
-        out_specs=[pl.BlockSpec((rows, w), lambda i: (0, 0)) for w in widths],
-        out_shape=[jax.ShapeDtypeStruct((rows, w), flat.dtype)
-                   for w in widths],
+        functools.partial(_unpack_kernel, ranges=ranges),
+        grid=(total // t,),
+        in_specs=[pl.BlockSpec((rows, t), lambda j: (0, j))],
+        out_specs=[_leaf_block(rows, t, *r) for r in ranges],
+        out_shape=out_shape,
         interpret=interpret,
     )(flat))
 

@@ -2,24 +2,18 @@
 jit'd wrappers in `kernels.ops` (kept out of ``ops`` so the kernel modules
 can import it without a cycle).
 
-Kernels are COMPILED BY DEFAULT wherever a non-CPU backend exists: the
-kernels target TPU, so on real accelerators the compiled path is the hot
-path and interpret mode is only a debugging tool. On CPU (this container,
-CI) the TPU lowering does not exist, so interpret mode — executing the
-kernel body op by op — stays the fallback that validates the kernel math.
+Kernels are COMPILED wherever a non-CPU backend exists: the kernels target
+TPU, so on real accelerators the compiled path is the hot path. On CPU
+(tests, CI) the TPU lowering does not exist, so interpret mode — executing
+the kernel body op by op — validates the kernel math.
 
-Resolution order, per call:
-
-1. an explicit ``interpret=`` argument wins;
-2. else ``REPRO_PALLAS_COMPILE`` decides when set (``1`` → compiled,
-   ``0`` → interpret; the launchers' ``--pallas-compile`` sets it, and it is
-   read dynamically so flipping it mid-process takes effect on the next
-   call);
-3. else the backend decides: compiled on TPU/GPU, interpret on CPU.
+Resolution, per call: an explicit ``interpret=True`` always interprets;
+otherwise the backend decides (interpret on CPU, compiled elsewhere). An
+explicit ``interpret=False`` on CPU asks for the TPU lowering, which only a
+compile for a described TPU (tests/test_tpu_compile.py) can use.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -30,7 +24,7 @@ from repro import telemetry
 def pallas_interpret(override: Optional[bool] = None,
                      kernel: Optional[str] = None) -> bool:
     """True → run the kernel in interpret mode. See the module docstring for
-    the resolution order (explicit > env var > backend default).
+    the resolution order (explicit ``True`` > backend default).
 
     ``kernel`` names the dispatch site for telemetry: each resolution with a
     name counts into the ``kernels.dispatch`` series (labels: kernel, mode),
@@ -40,11 +34,7 @@ def pallas_interpret(override: Optional[bool] = None,
     if override is not None:
         interpret = bool(override)
     else:
-        env = os.environ.get("REPRO_PALLAS_COMPILE")
-        if env is not None and env != "":
-            interpret = env != "1"
-        else:
-            interpret = jax.default_backend() == "cpu"
+        interpret = jax.default_backend() == "cpu"
     if kernel is not None:
         tel = telemetry.get()
         if tel.enabled:

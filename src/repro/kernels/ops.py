@@ -1,13 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 Execution mode is resolved PER CALL by `pallas_interpret`
-(`kernels/mode.py`): COMPILED BY DEFAULT wherever a non-CPU device exists,
-interpret as the CPU/CI fallback. Callers can force a mode through either
-the ``interpret=`` keyword or the ``REPRO_PALLAS_COMPILE`` environment
-variable (``--pallas-compile`` on the launchers sets it to ``1``; ``0``
-forces interpret for debugging on an accelerator). The env var is read
-dynamically, so flipping it mid-process takes effect on the next call; each
-mode jit-caches separately (``interpret`` is a static argname).
+(`kernels/mode.py`): compiled wherever a non-CPU device exists, interpret
+on CPU; ``interpret=True`` forces interpret mode. Each mode jit-caches
+separately (``interpret`` is a static argname).
 """
 from __future__ import annotations
 

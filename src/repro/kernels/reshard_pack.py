@@ -6,11 +6,14 @@ buckets according to the static Algorithm-1 tables. On GPU this is the
 torch.split + all_to_all prep in Fig. 12; on TPU we fuse the gather so the
 send buffer is produced in one VMEM pass.
 
-  grid = (n_dst,); per destination: s_max unit rows gathered by index from
-  the (U+1)-row zero-padded source (index U = pad ⇒ zero row).
+  grid = (n_dst, s_max, row_blocks); the (n_dst·s_max,) index table is
+  scalar-prefetched into SMEM and picks, per grid step, which source unit
+  row the pipeline DMAs into VMEM (index U = the zero pad row).
 
-Unit rows are 128-element multiples by construction (DESIGN.md §3.2), so
-each gathered row is lane-aligned.
+Each unit row is viewed as (rows, 128) lanes — unit rows are 128-element
+multiples by construction (DESIGN.md §3.2); other widths are zero-padded to
+one — and copied in row blocks of at most ``_BLOCK_BYTES``, so no VMEM
+block grows with the source or the unit size.
 """
 from __future__ import annotations
 
@@ -19,18 +22,29 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.mode import pallas_interpret
 
+_LANES = 128
+_BLOCK_BYTES = 1 << 20
 
-def _pack_kernel(idx_ref, src_ref, out_ref, *, s_max: int):
-    def body(s, _):
-        u = idx_ref[0, s]
-        row = src_ref[u]                       # dynamic gather (one unit row)
-        out_ref[0, s] = row
-        return 0
 
-    jax.lax.fori_loop(0, s_max, body, 0)
+def _pack_kernel(idx_ref, src_ref, out_ref):
+    del idx_ref  # consumed by the index maps
+    out_ref[...] = src_ref[...]
+
+
+def _row_block(rows: int, itemsize: int) -> int:
+    """Largest sublane-aligned divisor of ``rows`` within the block budget
+    (or ``rows`` itself, a legal full-extent block)."""
+    cap = max(8, _BLOCK_BYTES // (_LANES * itemsize))
+    if rows <= cap:
+        return rows
+    for rb in range(cap - cap % 8, 7, -8):
+        if rows % rb == 0:
+            return rb
+    return rows
 
 
 def reshard_pack(src, send_idx, *, interpret: bool | None = None):
@@ -43,15 +57,23 @@ def reshard_pack(src, send_idx, *, interpret: bool | None = None):
     up1, elems = src.shape
     n, s_max = send_idx.shape
     interpret = pallas_interpret(interpret)
-    kernel = functools.partial(_pack_kernel, s_max=s_max)
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, s_max), lambda i: (i, 0)),
-            pl.BlockSpec((up1, elems), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, s_max, elems), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, s_max, elems), src.dtype),
+    padded = -(-elems // _LANES) * _LANES
+    if padded != elems:
+        src = jnp.pad(src, ((0, 0), (0, padded - elems)))
+    rows = padded // _LANES
+    rb = _row_block(rows, src.dtype.itemsize)
+    out = pl.pallas_call(
+        _pack_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n, s_max, rows // rb),
+            in_specs=[pl.BlockSpec(
+                (None, rb, _LANES),
+                lambda i, s, r, idx: (idx[i * s_max + s], r, 0))],
+            out_specs=pl.BlockSpec(
+                (None, None, rb, _LANES), lambda i, s, r, idx: (i, s, r, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, s_max, rows, _LANES), src.dtype),
         interpret=interpret,
-    )(send_idx, src)
+    )(send_idx.reshape(-1).astype(jnp.int32), src.reshape(up1, rows, _LANES))
+    return out.reshape(n, s_max, padded)[..., :elems]

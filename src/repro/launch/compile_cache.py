@@ -1,0 +1,26 @@
+"""JAX's persistent compilation cache, shared by the launchers and
+``chip_smoke.py``: a second run of the same programs loads them instead of
+compiling again.
+
+The cache directory is part of what a later run looks up, so it never moves:
+``JAX_COMPILATION_CACHE_DIR`` when set (JAX reads that variable itself, and
+nothing here overrides it), else the fixed ``<repo>/.jax_cache`` (listed in
+``.gitignore``).
+"""
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn the persistent compilation cache on; returns its directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(REPO_CACHE_DIR))
+    return str(REPO_CACHE_DIR)

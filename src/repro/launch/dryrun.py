@@ -1,6 +1,7 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST be the first two lines, before any jax import: jax locks the device
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
+# ^ MUST run before any jax import: jax locks the device
 #   count on first init. 512 placeholder host devices back the production
 #   meshes (16×16 single-pod, 2×16×16 multi-pod). Never set this globally —
 #   smoke tests and benches see 1 device.

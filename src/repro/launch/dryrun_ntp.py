@@ -1,5 +1,6 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 # ^ first lines, before any jax import (see dryrun.py).
 """NTP-mode dry-run: lower the nonuniform-TP train step at the production
 mesh (data=16 × model=16) with degraded replicas, and account the reshard

@@ -1,5 +1,6 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 # ^ first lines, before any jax import (see dryrun.py).
 """Profiler with two modes.
 
@@ -138,6 +139,9 @@ def main():
     args = ap.parse_args()
     if args.telemetry and not args.measure:
         ap.error("--telemetry needs --measure (dry-run has no timed steps)")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.measure:
         if args.telemetry:
             from repro import telemetry

@@ -68,9 +68,6 @@ def main() -> None:
     ap.add_argument("--use-kernel", action="store_true",
                     help="route reshard send-bucket packing through the "
                          "Pallas reshard_pack kernel")
-    ap.add_argument("--pallas-compile", action="store_true",
-                    help="run Pallas kernels compiled (TPU) instead of "
-                         "interpret mode; sets REPRO_PALLAS_COMPILE=1")
     ap.add_argument("--telemetry", default=None, metavar="OUT.jsonl",
                     help="record the run's telemetry stream (admission, "
                          "preemptions, TTFT/TPOT, transition spans) as "
@@ -91,10 +88,6 @@ def main() -> None:
     if args.quarantine == "off" and args.trace is None:
         ap.error("--quarantine shapes the trace-driven SDC response; it "
                  "needs --trace")
-    if args.pallas_compile:
-        import os
-
-        os.environ["REPRO_PALLAS_COMPILE"] = "1"
     if args.telemetry:
         from repro import telemetry
 
@@ -105,9 +98,11 @@ def main() -> None:
 
     from repro.configs import get_arch, reduced
     from repro.core.failure_model import FailureTraceConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.runtime import event_kind, schedule_from_trace
     from repro.serve import Request, Router, ServeSession
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if not args.full:
         cfg = reduced(cfg)

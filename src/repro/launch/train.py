@@ -97,17 +97,14 @@ def main() -> None:
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--devices", type=int, default=0,
-                    help="fake CPU devices for a (2, n/2) test mesh")
-    ap.add_argument("--pallas-compile", action="store_true",
-                    help="run Pallas kernels compiled (TPU) instead of "
-                         "interpret mode; sets REPRO_PALLAS_COMPILE=1")
+                    help="devices of the (2, n/2) NTP mesh (default: every "
+                         "visible device); on CPU this many fake host "
+                         "devices are added to XLA_FLAGS")
     ap.add_argument("--telemetry", default=None, metavar="OUT.jsonl",
                     help="record the run's telemetry stream (spans, "
                          "counters, gauges) as JSONL; fold it offline with "
                          "python -m repro.launch.telemetry_report OUT.jsonl")
     args = ap.parse_args()
-    if args.pallas_compile:
-        os.environ["REPRO_PALLAS_COMPILE"] = "1"
     if args.telemetry:
         from repro import telemetry
 
@@ -159,12 +156,15 @@ def main() -> None:
         ap.error("spares with --pp > 1 need the global allocator: pass "
                  "--allocator greedy")
 
-    if args.dry_run:
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-    elif args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}"
-        )
+    host_devices = 512 if args.dry_run else args.devices
+    if host_devices:
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+            os.environ.get("XLA_FLAGS"),
+            f"--xla_force_host_platform_device_count={host_devices}",
+        )))
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.ntp:
         _run_ntp(args)
@@ -248,10 +248,14 @@ def _run_ntp(args) -> None:
     from repro.optim import AdamWConfig, adamw
     from repro.runtime import FailureEvent, NTPModelConfig, NTPSession, power_policy
 
-    n_dev = args.devices or 8
-    if len(jax.devices()) < n_dev:
+    devices = jax.devices()
+    n_dev = args.devices or len(devices)
+    if n_dev < 2 or n_dev % 2 or len(devices) < n_dev:
         raise SystemExit(
-            f"need {n_dev} devices: pass --devices {n_dev} (or set XLA_FLAGS)"
+            f"--ntp trains 2 DP replicas on a (2, n/2) mesh: it needs an "
+            f"even number >= 2 of devices, asked for {n_dev} and "
+            f"{len(devices)} {devices[0].platform} device(s) are visible "
+            f"(on CPU, --devices N adds N fake host devices)"
         )
     if args.fail_at is not None and not 0 <= args.fail_replica < 2:
         raise SystemExit(

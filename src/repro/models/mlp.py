@@ -215,7 +215,7 @@ def moe_apply_expert_parallel(cfg: ArchConfig, p: dict, x, ctx: ShardCtx):
     """
     from jax.sharding import PartitionSpec
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     m = cfg.moe
     mesh, tp = ctx.mesh, ctx.tp

@@ -46,6 +46,7 @@ from typing import Any, Dict, List, Optional, Union
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro import telemetry
 from repro.checkpoint import load_checkpoint, save_checkpoint
@@ -234,6 +235,7 @@ class NTPSession:
         )
         self._params = nt.pack_params(cfg, canonical, self._plan)
         self._opt = self._optimizer.init(self._params)
+        self._place_state()
         if self._allocator is not None:
             # calibrate move pricing from the LIVE trees: predicted bytes of
             # a candidate transition then equal the executed TransferStats
@@ -617,6 +619,7 @@ class NTPSession:
         tree, step = load_checkpoint(path, like)
         self._params = nt.pack_params(self._cfg, tree["params"], self._plan)
         self._opt = self._pack_opt(tree["opt"])
+        self._place_state()
         return step if step is not None else self.opt_step
 
     def snapshot(self) -> None:
@@ -648,6 +651,7 @@ class NTPSession:
             self._cfg, self._snapshot["params"], self._plan
         )
         self._opt = self._pack_opt(self._snapshot["opt"])
+        self._place_state()
         self.last_rollback = True
         return self.opt_step
 
@@ -671,6 +675,31 @@ class NTPSession:
                 "launch/train.py --ntp instead of --arch — to use lifecycle "
                 "events, canonical checkpoints, or power policies."
             )
+
+    def _place_state(self) -> None:
+        """Commit the packed params and param-like optimizer trees to the
+        mesh with the step's shard_map specs (unit buffers split over
+        (data, model), the rest replicated). The first step after a
+        (re)pack then sees the same input shardings as the steps after it,
+        so it compiles one program, not two. Staged submesh meshes place
+        their own stacked trees (core/pp_submesh); a mesh stand-in without
+        devices holds nothing."""
+        if (not isinstance(self._mesh, Mesh)
+                or "stage" in self._mesh.axis_names):
+            return
+
+        def place(tree):
+            return jax.device_put(tree, jax.tree.map(
+                lambda spec: NamedSharding(self._mesh, spec),
+                nt._tree_specs(tree)))
+
+        replicated = NamedSharding(self._mesh, PartitionSpec())
+        self._params = place(self._params)
+        self._opt = {
+            k: (place(v) if k in self._optimizer.param_like
+                else jax.device_put(v, replicated))
+            for k, v in self._opt.items()
+        }
 
     def _staged_replan(self, health: StagedHealth, *, current):
         """One pp>1 replan: the global allocator when bound (joint spares /
@@ -791,6 +820,7 @@ class NTPSession:
         moved, stats = transition_trees(self._cfg, trees, old, new)
         self._params = moved[0]
         self._opt = dict(opt, **dict(zip(opt_keys, moved[1:])))
+        self._place_state()
         self.last_transition = stats
 
     def _transition_staged(self, old: StagedPlan, new: StagedPlan) -> None:
@@ -809,6 +839,7 @@ class NTPSession:
         )
         self._params = moved[0]
         self._opt = dict(opt, **dict(zip(opt_keys, moved[1:])))
+        self._place_state()
         self.last_transition = stats
 
     def _canonical_opt(self) -> Dict:

@@ -59,6 +59,7 @@ def test_rmsnorm(n, d, br, plus_one, dtype):
 
 @pytest.mark.parametrize("bh,s,hp,ds,chunk", [
     (2, 64, 16, 32, 16), (3, 128, 16, 32, 32), (1, 256, 64, 128, 64),
+    (2, 512, 64, 128, 128),   # TPU-legal tiles, 4 chunks per row
 ])
 def test_ssd_scan(bh, s, hp, ds, chunk):
     x = jnp.asarray(RNG.normal(size=(bh, s, hp)), jnp.float32)
@@ -95,7 +96,10 @@ def test_ssd_kernel_matches_model_path():
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 5e-4
 
 
-@pytest.mark.parametrize("u,elems,n,smax", [(10, 8, 4, 5), (33, 128, 8, 9)])
+@pytest.mark.parametrize("u,elems,n,smax", [
+    (10, 8, 4, 5), (33, 128, 8, 9),
+    (3, 128 * 4096, 2, 2),    # unit rows copied in 2 row blocks each
+])
 def test_reshard_pack(u, elems, n, smax):
     src = jnp.asarray(
         np.vstack([RNG.normal(size=(u, elems)), np.zeros((1, elems))]),

@@ -104,7 +104,10 @@ def _leaves(rng, rows, widths):
             for w in widths]
 
 
-@pytest.mark.parametrize("widths", [(3,), (1, 1), (4, 2, 7), (8, 8, 8, 8)])
+@pytest.mark.parametrize("widths", [(3,), (1, 1), (4, 2, 7), (8, 8, 8, 8),
+                                    # 128-aligned: column-tile grids of
+                                    # 6 and 3 tiles
+                                    (256, 384, 128), (1024, 2048)])
 def test_bucket_pack_unpack_matches_ref(widths):
     import jax.numpy as jnp
 

@@ -73,8 +73,9 @@ def test_stack_staged_params_geometry_and_content():
 
     for key in ("ln1", "ln2"):
         leaf = np.asarray(stacked["rep"][key])
-        # replicated on purpose: sharding these P("stage") trips a jax 0.4.x
-        # partitioner bug when the stack is traced into the step's jit
+        # replicated on purpose: sharding these P("stage") tripped a
+        # partitioner bug on jax 0.4.x when the stack was traced into the
+        # step's jit (not re-checked on the installed jax 0.9)
         assert specs["rep"][key] == P()
         assert leaf.shape[:2] == (2, l_max)
         for s, layers in enumerate(stage_layers):

@@ -1,10 +1,10 @@
 """Pallas `reshard_pack` kernel-vs-jnp parity across BOTH execution modes:
-kernels are compiled by default wherever a non-CPU device exists and fall
-back to interpret on CPU (`kernels.mode.pallas_interpret`: explicit
-``interpret=`` > ``REPRO_PALLAS_COMPILE`` env > backend default, read per
-call). Interpret mode must match the plain jnp gather bit-for-bit on every
-backend; compiled mode is asserted identical too wherever the backend can
-lower Pallas (TPU/GPU), and skips cleanly on CPU."""
+kernels are compiled wherever a non-CPU device exists and interpreted on
+CPU (`kernels.mode.pallas_interpret`: explicit ``interpret=True`` >
+backend default, read per call). Interpret mode must match the plain jnp
+gather bit-for-bit on every backend; compiled mode is asserted identical
+too wherever the backend can lower Pallas (TPU/GPU), and skips cleanly on
+CPU."""
 import numpy as np
 import pytest
 
@@ -30,17 +30,18 @@ def _jnp_gather(xp, send_idx):
 
 
 def test_pallas_interpret_flag_resolution(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
+    from repro.kernels import mode
+
     # backend default: compiled on an accelerator, interpret on CPU
     assert ops.pallas_interpret() is (jax.default_backend() == "cpu")
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    assert ops.pallas_interpret() is False          # env threads through
     assert ops.pallas_interpret(True) is True       # explicit override wins
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "0")
-    assert ops.pallas_interpret() is True           # force-interpret debug
     assert ops.pallas_interpret(False) is False
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "")
-    assert ops.pallas_interpret() is (jax.default_backend() == "cpu")
+    # on an accelerator only an explicit True interprets; nothing in the
+    # environment turns interpret mode on
+    monkeypatch.setattr(mode.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "0")
+    assert ops.pallas_interpret() is False
+    assert ops.pallas_interpret(True) is True
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -57,8 +58,8 @@ def test_kernel_interpret_matches_jnp(seed):
 
 
 def test_kernel_compiled_matches_jnp_or_skips():
-    """Compiled mode (the REPRO_PALLAS_COMPILE=1 / --pallas-compile route):
-    bit-identical to the jnp gather where the backend lowers Pallas."""
+    """Compiled mode: bit-identical to the jnp gather where the backend
+    lowers Pallas."""
     x, tables = _case(3)
     flat = x.reshape(x.shape[0], x.shape[1], -1)
     idx = jnp.asarray(tables.send_idx[0])
@@ -96,17 +97,15 @@ def test_engine_kernel_route_matches_jnp_route():
 
 
 def test_reshard_pack_env_threading(monkeypatch):
-    """ops.reshard_pack with no explicit flag follows the env var at CALL
-    time (not import time) — the CLI launchers rely on this."""
+    """ops.reshard_pack with no explicit flag resolves its mode from the
+    backend at CALL time, not from the environment: setting the retired
+    REPRO_PALLAS_COMPILE variable changes nothing."""
     x, tables = _case(4)
     flat = x.reshape(x.shape[0], x.shape[1], -1)
     idx = jnp.asarray(tables.send_idx[0])
     monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
     want = np.asarray(ops.reshard_pack(flat[0], idx))
     monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    try:
-        got = np.asarray(jax.block_until_ready(ops.reshard_pack(flat[0], idx)))
-    except Exception as e:
-        pytest.skip(f"backend {jax.default_backend()!r} cannot compile "
-                    f"Pallas: {type(e).__name__}")
+    got = np.asarray(jax.block_until_ready(ops.reshard_pack(flat[0], idx)))
     assert np.array_equal(want, got)
+    assert np.array_equal(want, np.asarray(_jnp_gather(x, tables.send_idx))[0])
