@@ -12,8 +12,9 @@ cut, random weights from ``--seed``):
 * train/ntp    ``NTPSession.create`` on a data=1 x model=1 mesh: the
                shard_map NTP step through the TPU compiler;
 * serve        ``ServeSession`` + ``Router`` (the ``launch/serve.py --full``
-               path): one failure and one repair mid-decode, every greedy
-               stream equal to a run with no events;
+               path, at its default matmul precision): one failure and one
+               repair mid-decode, every greedy stream equal to a run with
+               no events;
 * kernels      every Pallas kernel through ``kernels.ops``, dispatched
                compiled, against its jnp reference.
 
@@ -226,11 +227,15 @@ def serve_failover(cfg, *, requests: int, prompt_len: int, max_new: int,
     check(len(want) == requests and set(got) == set(want),
           f"serve: completed {sorted(got)} vs {sorted(want)}")
     for rid in want:
+        first = next((i for i, (a, b) in enumerate(zip(got[rid], want[rid]))
+                      if a != b), min(len(got[rid]), len(want[rid])))
         check(len(want[rid]) == max_new and got[rid] == want[rid],
-              f"serve: request {rid} diverged through fail/repair:\n"
-              f"  faulty {got[rid]}\n  ref    {want[rid]}")
+              f"serve: request {rid} diverged through fail/repair at "
+              f"generated token {first}:\n  faulty {got[rid]}\n"
+              f"  ref    {want[rid]}")
     g = faulty.goodput()
-    print(f"serve: {requests} requests x {max_new} tokens, streams equal "
+    print(f"serve: {requests} requests x {max_new} tokens at default matmul "
+          f"precision, streams equal "
           f"through fail->repair ({ticks} ticks, {g['preemptions']} "
           f"preemptions) and with no events ({ref_ticks} ticks); smoke "
           f"wall {t_faulty:.2f} s / {t_ref:.2f} s (include compile)")
@@ -458,11 +463,10 @@ def main() -> int:
                       seq=NTP_SEQ, local_batch=NTP_LOCAL_BATCH,
                       steps=TRAIN_STEPS, seed=args.seed)
         with meter.phase("serve (fail -> repair mid-decode)"):
-            with jax.default_matmul_precision("highest"):
-                serve_failover(cfg, requests=SERVE_REQUESTS,
-                               prompt_len=SERVE_PROMPT, max_new=SERVE_NEW,
-                               fail_tick=SERVE_NEW // 4,
-                               repair_tick=SERVE_NEW // 2, seed=args.seed)
+            serve_failover(cfg, requests=SERVE_REQUESTS,
+                           prompt_len=SERVE_PROMPT, max_new=SERVE_NEW,
+                           fail_tick=SERVE_NEW // 4,
+                           repair_tick=SERVE_NEW // 2, seed=args.seed)
         with meter.phase("kernels"):
             kernels(cfg, seq=TRAIN_SEQ, batch=TRAIN_BATCH, seed=args.seed)
 

@@ -36,10 +36,8 @@ def test_pallas_interpret_flag_resolution(monkeypatch):
     assert ops.pallas_interpret() is (jax.default_backend() == "cpu")
     assert ops.pallas_interpret(True) is True       # explicit override wins
     assert ops.pallas_interpret(False) is False
-    # on an accelerator only an explicit True interprets; nothing in the
-    # environment turns interpret mode on
+    # on an accelerator only an explicit True interprets
     monkeypatch.setattr(mode.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "0")
     assert ops.pallas_interpret() is False
     assert ops.pallas_interpret(True) is True
 
@@ -95,17 +93,3 @@ def test_engine_kernel_route_matches_jnp_route():
     c = emulate_tables(np.asarray(x), tables)
     assert np.array_equal(np.asarray(a), c)
 
-
-def test_reshard_pack_env_threading(monkeypatch):
-    """ops.reshard_pack with no explicit flag resolves its mode from the
-    backend at CALL time, not from the environment: setting the retired
-    REPRO_PALLAS_COMPILE variable changes nothing."""
-    x, tables = _case(4)
-    flat = x.reshape(x.shape[0], x.shape[1], -1)
-    idx = jnp.asarray(tables.send_idx[0])
-    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
-    want = np.asarray(ops.reshard_pack(flat[0], idx))
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    got = np.asarray(jax.block_until_ready(ops.reshard_pack(flat[0], idx)))
-    assert np.array_equal(want, got)
-    assert np.array_equal(want, np.asarray(_jnp_gather(x, tables.send_idx))[0])

@@ -379,6 +379,45 @@ def test_require_ntp_names_caller_and_alternative():
     assert set(canon) >= {"embed", "head", "layers"}
 
 
+@pytest.mark.parametrize("optimizer", [sgd(0.05), adamw(AdamWConfig(lr=1e-3))],
+                         ids=["sgd", "adamw"])
+def test_session_step_compiles_once_across_repacks(optimizer):
+    """The packed state is committed with the step's shardings after init
+    and after every repack, so the step compiles once: the second step,
+    and the first step after a rollback repack, compile nothing. Without
+    that placement each repack costs a second compile of the step."""
+    import jax.numpy as jnp
+    from repro.runtime import NTPSession
+
+    cfg = _tiny_cfg()
+    compiles = []
+
+    def listener(name, secs, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    session = NTPSession.create(cfg, jax.make_mesh((1, 1), ("data", "model")),
+                                local_batch=2, optimizer=optimizer,
+                                key=jax.random.PRNGKey(0))
+    batch = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab, (2, 9)), jnp.int32)
+
+    def step_compiles():
+        n = len(compiles)
+        jax.block_until_ready(session.step(batch))
+        return len(compiles) - n
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        step_compiles()
+        session.snapshot()
+        assert step_compiles() == 0
+        session.rollback()
+        assert step_compiles() == 0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
 # ---------------------------------------------------------------------------
 # live session transition (8 fake devices, subprocess)
 
