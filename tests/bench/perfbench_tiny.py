@@ -1,0 +1,62 @@
+"""Tiny versions of the benchmark's cells, for driving the harness on the
+CPU: the cells' own traffic files and drivers, with every size cut so that
+a run takes seconds. The look for a chip is skipped; everything after it
+runs as on the chip."""
+from __future__ import annotations
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+from bench import harness  # noqa: E402
+
+TINY_MODEL = {"hidden_size": 64, "intermediate_size": 256,
+              "num_attention_heads": 4, "num_key_value_heads": 2,
+              "head_dim": 16, "num_hidden_layers": 2, "vocab_size": 300}
+TINY_TRAFFIC = {"granite-train": {"batch": 2, "seq": 32}}
+# limits for the tiny CPU sizes, set from their readings: the program
+# agrees with the reference to f32 rounding (loss 5e-7, gradient norms
+# 8e-7, change 2e-5)
+TINY_LIMITS = {"loss_gap": 1e-4, "grad_norm_gap": 1e-4,
+               "change_norm_gap": 1e-3}
+
+
+CELLS = {  # workload: (chips, configuration, traffic mix)
+    "granite-train": (1, "granite-3-2b-train", "train-2x4096"),
+}
+
+
+def resized(sizes: dict, model: dict) -> dict:
+    """``sizes`` with the model's sizes replaced; the attention scale the
+    program runs follows the head size."""
+    out = dict(sizes, **model)
+    dep = dict(out["departures"])
+    dep["attention_multiplier"] = dict(dep["attention_multiplier"],
+                                       run=out["head_dim"] ** -0.5)
+    out["departures"] = dep
+    return out
+
+
+def tiny_cell(workload: str, **traffic) -> harness.Cell:
+    chips, config, mix = CELLS[workload]
+    cell = harness.make_cell(workload, chips,
+                             ROOT / "bench" / "configs" / f"{config}.json", mix)
+    cell.sizes = resized(cell.sizes, TINY_MODEL)
+    cell.traffic = {**cell.traffic, **TINY_TRAFFIC[workload], **traffic}
+    return cell
+
+
+def tiny_run(workload: str, *, seed: int = 2**31 + 11, seconds: float = 2.0,
+             limits=None, readings=False, devices=None, **traffic):
+    import jax
+
+    cell = tiny_cell(workload, **traffic)
+    run = harness.Run(cell=cell, seed=seed, seconds=seconds, trace=False,
+                      devices=devices or jax.devices()[: cell.chips],
+                      meter=harness.CompileMeter(),
+                      limits=limits or TINY_LIMITS, readings=readings)
+    return run, cell.driver.run(run)
