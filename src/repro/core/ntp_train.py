@@ -251,11 +251,13 @@ def _psum(x, axis):
     return jax.lax.psum(x, axis) if axis is not None else x
 
 
+@jax.named_scope("norm")
 def _rms(x, w):
     v = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(v + 1e-6) * w
 
 
+@jax.named_scope("attention")
 def _attn_local(lp, h, cfg: NTPModelConfig, model_axis="model"):
     """h: (B,S,d) replicated; unit-buffered weights (U, d, ...)."""
     b, s, d = h.shape
@@ -274,12 +276,14 @@ def _attn_local(lp, h, cfg: NTPModelConfig, model_axis="model"):
     return _psum(y, model_axis)
 
 
+@jax.named_scope("mlp")
 def _mlp_local(lp, h, model_axis="model"):
     a = jax.nn.gelu(jnp.einsum("bsd,udf->bsuf", h, lp["A"]))
     z = jnp.einsum("bsuf,ufd->bsd", a, lp["B"])
     return _psum(z, model_axis)
 
 
+@jax.named_scope("mlp")
 def _moe_local(lp, h, unit_ids, cfg: NTPModelConfig, model_axis="model"):
     """NTP-MoE ffn: partition unit = whole expert (DESIGN.md §4). Each rank
     computes its local expert units on all tokens (dense-masked prototype
@@ -315,7 +319,8 @@ def _forward_totals(cfg: NTPModelConfig, params, tokens, sample_mask,
     ``moe_unit_ids`` is either one (U,) slot-id array shared by every layer
     (uniform plan) or a per-layer sequence (staged plans differ by stage)."""
     inp, tgt = tokens[:, :-1], tokens[:, 1:]
-    x = params["embed"][inp]
+    with jax.named_scope("embed"):
+        x = params["embed"][inp]
     per_layer = isinstance(moe_unit_ids, (list, tuple))
     for i, lp in enumerate(params["layers"]):
         uids = moe_unit_ids[i] if per_layer else moe_unit_ids
@@ -324,11 +329,13 @@ def _forward_totals(cfg: NTPModelConfig, params, tokens, sample_mask,
             x = x + _moe_local(lp, _rms(x, lp["ln2"]), uids, cfg, model_axis)
         else:
             x = x + _mlp_local(lp, _rms(x, lp["ln2"]), model_axis)
-    logits = jnp.einsum("bsd,dv->bsv", _rms(x, params["final_norm"]), params["head"])
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
-    tok_loss = (lse - ll) * sample_mask[:, None]
+    with jax.named_scope("loss_head"):
+        logits = jnp.einsum("bsd,dv->bsv", _rms(x, params["final_norm"]),
+                            params["head"])
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        ll = jnp.take_along_axis(logits, tgt[..., None], axis=-1)[..., 0]
+        tok_loss = (lse - ll) * sample_mask[:, None]
     return tok_loss.sum(), (sample_mask[:, None] * jnp.ones_like(tok_loss)).sum()
 
 
@@ -559,9 +566,11 @@ def make_ntp_train_step(
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(global_loss)(params, batch)
         grads = sync_grads(grads)
-        new_params, new_state, metrics = optimizer.update(
-            grads, opt_state, params, norm_weights=_norm_weights(grads, d_axis)
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_state, metrics = optimizer.update(
+                grads, opt_state, params,
+                norm_weights=_norm_weights(grads, d_axis),
+            )
         metrics = dict(metrics, loss=loss)
         return new_params, new_state, metrics
 
@@ -668,9 +677,11 @@ def _make_staged_train_step(
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(global_loss)(params, batch)
         grads = sync_grads(grads)
-        new_params, new_state, metrics = optimizer.update(
-            grads, opt_state, params, norm_weights=_norm_weights(grads, d_axis)
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_state, metrics = optimizer.update(
+                grads, opt_state, params,
+                norm_weights=_norm_weights(grads, d_axis),
+            )
         metrics = dict(metrics, loss=loss)
         return new_params, new_state, metrics
 

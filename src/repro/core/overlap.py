@@ -486,10 +486,11 @@ def make_overlapped_train_step(
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def step(params, opt_state, batch):
         loss, grads = loss_and_grads(params, batch)
-        new_params, new_state, metrics = optimizer.update(
-            grads, opt_state, params,
-            norm_weights=nt._norm_weights(grads, d_axis),
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_state, metrics = optimizer.update(
+                grads, opt_state, params,
+                norm_weights=nt._norm_weights(grads, d_axis),
+            )
         metrics = dict(metrics, loss=loss)
         return new_params, new_state, metrics
 

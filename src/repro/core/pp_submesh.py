@@ -348,10 +348,11 @@ def make_submesh_train_step(
     def _step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(global_loss)(params, batch)
         grads = sync_grads(grads)
-        new_params, new_state, metrics = optimizer.update(
-            grads, opt_state, params,
-            norm_weights=nt._norm_weights(grads, d_axis),
-        )
+        with jax.named_scope("optimizer"):
+            new_params, new_state, metrics = optimizer.update(
+                grads, opt_state, params,
+                norm_weights=nt._norm_weights(grads, d_axis),
+            )
         metrics = dict(metrics, loss=loss)
         return new_params, new_state, metrics
 

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
 from repro.configs.base import ArchConfig
 from repro.configs.shapes import ShapeSpec
 from repro.models.common import sanitize_spec
@@ -115,14 +116,15 @@ class SyntheticLMPipeline:
         return np.concatenate(toks, axis=1).astype(np.int32)  # (B, S+1)
 
     def batch(self, step: int) -> Dict[str, jnp.ndarray]:
-        arr = self._batch_np(step)
-        tokens, targets = arr[:, :-1], arr[:, 1:]
-        if self.mesh is not None:
-            sh = NamedSharding(self.mesh, P(self.dp_axes))
-            tokens = jax.device_put(tokens, sh)
-            targets = jax.device_put(targets, sh)
-        else:
-            tokens, targets = jnp.asarray(tokens), jnp.asarray(targets)
+        with telemetry.get().span("data.batch"):
+            arr = self._batch_np(step)
+            tokens, targets = arr[:, :-1], arr[:, 1:]
+            if self.mesh is not None:
+                sh = NamedSharding(self.mesh, P(self.dp_axes))
+                tokens = jax.device_put(tokens, sh)
+                targets = jax.device_put(targets, sh)
+            else:
+                tokens, targets = jnp.asarray(tokens), jnp.asarray(targets)
         return {"tokens": tokens, "targets": targets}
 
     def __iter__(self) -> Iterator[Dict[str, jnp.ndarray]]:

@@ -168,6 +168,7 @@ def _attend_flash(q, k, v, q_pos, k_pos, kind, window, chunk, cap):
 # ---------------------------------------------------------------------------
 # public apply
 
+@jax.named_scope("attention")
 def attn_apply(
     cfg: ArchConfig,
     p: dict,

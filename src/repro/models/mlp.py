@@ -41,6 +41,7 @@ def mlp_specs(cfg: ArchConfig, tp: str = "model") -> dict:
     return s
 
 
+@jax.named_scope("mlp")
 def mlp_apply(cfg: ArchConfig, p: dict, x, ctx: ShardCtx):
     act = act_fn(cfg.ffn_act)
     h = jnp.einsum("bsd,df->bsf", x, p["w_up"])
@@ -97,6 +98,7 @@ def _route(m: MoESpec, logits):
     return idx, w, probs
 
 
+@jax.named_scope("mlp")
 def moe_apply(cfg: ArchConfig, p: dict, x, ctx: ShardCtx) -> Tuple[jnp.ndarray, dict]:
     """Returns (out, aux) — aux carries the load-balance loss (Switch-style)."""
     m = cfg.moe
@@ -202,6 +204,7 @@ def _local_dispatch_compute(cfg: ArchConfig, p_local, xf, cap: int):
     return out, f, probs.mean(0)
 
 
+@jax.named_scope("mlp")
 def moe_apply_expert_parallel(cfg: ArchConfig, p: dict, x, ctx: ShardCtx):
     """§Perf iteration A1 (beyond-paper): two-stage MoE dispatch.
 

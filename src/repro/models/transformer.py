@@ -41,6 +41,7 @@ def norm_specs(cfg: ArchConfig) -> dict:
     return s
 
 
+@jax.named_scope("norm")
 def norm_apply(cfg: ArchConfig, p: dict, x):
     if cfg.norm_type == "ln":
         return layer_norm(x, p["w"], p["b"], cfg.norm_eps)
@@ -360,6 +361,7 @@ class Model:
         )
 
     # ---- forward ---------------------------------------------------------
+    @jax.named_scope("embed")
     def _embed(self, params, tokens, positions):
         cfg = self.cfg
         x = jnp.take(params["embed"], tokens, axis=0)
@@ -435,6 +437,7 @@ class Model:
             new_cache["tail"] = tuple(tail_caches)
         return x, aux, (new_cache if cache is not None else None)
 
+    @jax.named_scope("loss_head")
     def _logits(self, params, x):
         cfg = self.cfg
         x = norm_apply(cfg, params["final_norm"], x)

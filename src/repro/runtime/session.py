@@ -72,7 +72,10 @@ class NTPSession:
 
     @classmethod
     def _new(cls) -> "NTPSession":
-        return object.__new__(cls)
+        self = object.__new__(cls)
+        self._host_step = 0       # steps dispatched: the profiler's step_num
+        self._fresh_step = True   # the next step traces and compiles
+        return self
 
     @classmethod
     def create(
@@ -431,22 +434,29 @@ class NTPSession:
         ``policy``, ``power_boost`` (max ×TDP over replicas) and the
         predicted ``rel_iter_time``.
 
-        With telemetry active the step is wrapped in a ``session.step``
-        span. NOTE the span times DISPATCH (jax is async; nothing here
-        blocks on device work — that would change recorder-off numerics'
-        timing); wall-per-step lives in the orchestrator/bench spans that
-        own the `block_until_ready`."""
+        The step is wrapped in a ``session.step`` span inside a profiler
+        step marker (``StepTraceAnnotation("train", step_num=...)``, counted
+        on the host); the first step after the step function was (re)built
+        carries ``compiled=True``, since it pays for the trace and compile.
+        NOTE the span times DISPATCH (jax is async; nothing here blocks on
+        device work — that would change recorder-off numerics' timing);
+        wall-per-step lives in the orchestrator/bench spans that own the
+        `block_until_ready`."""
         tel = telemetry.get()
-        with tel.span("session.step", backend=self._backend, pp=self._pp,
-                      overlap="on" if self._overlap else "off"):
+        with jax.profiler.StepTraceAnnotation("train",
+                                              step_num=self._host_step), \
+                tel.span("session.step", backend=self._backend, pp=self._pp,
+                         overlap="on" if self._overlap else "off") as sp:
+            if self._fresh_step:
+                sp.set(compiled=True)
             self._params, self._opt, metrics = self._step_fn(
                 self._params, self._opt, batch
             )
+        self._fresh_step = False
+        self._host_step += 1
         if tel.enabled and self._decision is not None:
             tel.gauge("train.rel_iter_time", self._decision.rel_iter_time,
                       source="analytic", policy=self._decision.method)
-            tel.gauge("train.power_boost", self._decision.max_boost,
-                      policy=self._decision.method)
         if self._decision is not None:
             metrics = dict(
                 metrics,
@@ -528,11 +538,14 @@ class NTPSession:
         Returns the new plan (`FailurePlan` for pp=1, `StagedPlan` else).
 
         With telemetry active the whole replan+repack is one
-        ``session.transition`` span: phase marks ``planned``/``executed``
-        and, when state moved, the executed `TransferStats` ledger attached
-        as attributes — the span's byte counts equal ``last_transition``
-        exactly (the Perfetto trace carries the same numbers the tests
-        assert against)."""
+        ``session.transition`` span: phase marks ``planned``, then, when
+        state moves, ``gathered`` (state on the host), ``repacked``,
+        ``placed`` (the repacked state on the devices: the span waits for
+        it) and ``executed``, with the executed `TransferStats` ledger
+        attached as attributes — the span's byte counts equal
+        ``last_transition`` exactly (the Perfetto trace carries the same
+        numbers the tests assert against). The new step program is traced
+        and compiled by the next `step`, whose span says ``compiled``."""
         self._require_ntp("lifecycle replanning")
 
         tel = telemetry.get()
@@ -574,10 +587,7 @@ class NTPSession:
                 return self._plan
 
             old_plan = self._plan
-            if self._pp == 1:
-                self._transition(old_plan, new_plan)
-            else:
-                self._transition_staged(old_plan, new_plan)
+            self._transition(old_plan, new_plan, sp)
             sp.mark("executed")
             sp.set(changed=True, old_plan=str(old_plan),
                    new_plan=str(new_plan), **self.last_transition.as_dict())
@@ -797,6 +807,7 @@ class NTPSession:
             lbs = tuple(self.local_batches)
         else:
             lbs = None  # the builder's default rule — binary path unchanged
+        self._fresh_step = True
         self._step_fn = nt.make_ntp_train_step(
             self._cfg, self._plan, self._mesh, mode=self._mode,
             local_batch=self._local_batch, optimizer=self._optimizer,
@@ -805,41 +816,38 @@ class NTPSession:
             overlap=self._overlap,
         )
 
-    def _transition(self, old: FailurePlan, new: FailurePlan) -> None:
+    def _transition(self, old, new, sp) -> None:
         """One fused packed→packed transition for params AND every
         param-like optimizer leaf tree (AdamW m/v/master): all of them ride
         the same per-(replica, src, dst) buckets, so the whole fail/repair
         move is one bucketed send per rank pair — O(moved units), not
-        O(model), host traffic (repro.reshard.transition). The transfer
-        accounting is kept in `last_transition`."""
-        from repro.reshard.transition import transition_trees
-
-        opt = jax.device_get(self._opt)
-        opt_keys = [k for k in self._optimizer.param_like if k in opt]
-        trees = [jax.device_get(self._params)] + [opt[k] for k in opt_keys]
-        moved, stats = transition_trees(self._cfg, trees, old, new)
-        self._params = moved[0]
-        self._opt = dict(opt, **dict(zip(opt_keys, moved[1:])))
-        self._place_state()
-        self.last_transition = stats
-
-    def _transition_staged(self, old: StagedPlan, new: StagedPlan) -> None:
-        """Stage-local transitions via `transition_staged_trees`: only the
-        stages whose plan changed repack their layer slice (their own
-        per-(replica, src, dst) buckets, tagged by stage). The session owns
-        its trees exclusively, so untouched stages pass through with zero
-        bytes and zero copies (``copy_unchanged=False``)."""
-        from repro.reshard.transition import transition_staged_trees
-
-        opt = jax.device_get(self._opt)
-        opt_keys = [k for k in self._optimizer.param_like if k in opt]
-        trees = [jax.device_get(self._params)] + [opt[k] for k in opt_keys]
-        moved, stats = transition_staged_trees(
-            self._cfg, trees, old, new, copy_unchanged=False
+        O(model), host traffic (repro.reshard.transition). For pp > 1 only
+        the stages whose plan changed repack their layer slice; the session
+        owns its trees exclusively, so untouched stages pass through with
+        zero bytes and zero copies (``copy_unchanged=False``). The transfer
+        accounting is kept in `last_transition`; ``sp`` (the
+        ``session.transition`` span) gets the phase marks."""
+        from repro.reshard.transition import (
+            transition_staged_trees, transition_trees,
         )
+
+        opt = jax.device_get(self._opt)
+        opt_keys = [k for k in self._optimizer.param_like if k in opt]
+        trees = [jax.device_get(self._params)] + [opt[k] for k in opt_keys]
+        sp.mark("gathered")
+        if self._pp == 1:
+            moved, stats = transition_trees(self._cfg, trees, old, new)
+        else:
+            moved, stats = transition_staged_trees(
+                self._cfg, trees, old, new, copy_unchanged=False
+            )
+        sp.mark("repacked")
         self._params = moved[0]
         self._opt = dict(opt, **dict(zip(opt_keys, moved[1:])))
         self._place_state()
+        if telemetry.get().enabled:
+            jax.block_until_ready((self._params, self._opt))
+        sp.mark("placed")
         self.last_transition = stats
 
     def _canonical_opt(self) -> Dict:

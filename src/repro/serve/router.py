@@ -165,10 +165,6 @@ class Router:
         """Tokens/tick per replica and overall, plus SLO attainment."""
         ticks = max(self.now, 1.0)
         per = [e.stats["tokens"] / ticks for e in self.session.engines]
-        tel = telemetry.get()
-        if tel.enabled:
-            for r, g in enumerate(per):
-                tel.gauge("serve.replica_goodput", g, replica=str(r))
         return {
             "per_replica": per,
             "tokens_per_tick": float(sum(per)),

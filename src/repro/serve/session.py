@@ -259,14 +259,9 @@ class ServeSession:
                 })
             sp.set(domain=dom, preempted=len(preempted),
                    bytes_moved=reshard_bytes)
-            if tel.enabled:
-                if preempted:
-                    tel.counter("serve.preempted", len(preempted),
-                                policy=self._policy)
-                for r, engine in enumerate(self.engines):
-                    tel.gauge("serve.replica_rate",
-                              engine.rel_speed * engine.capacity,
-                              replica=str(r))
+            if tel.enabled and preempted:
+                tel.counter("serve.preempted", len(preempted),
+                            policy=self._policy)
         return preempted
 
     def _apply_degradation(self, event, dom: int) -> List[Request]:
@@ -305,11 +300,6 @@ class ServeSession:
                         "rel_speed": speed, "draining": draining,
                     })
             sp.set(domain=dom, preempted=0)
-            if tel.enabled:
-                for r, engine in enumerate(self.engines):
-                    tel.gauge("serve.replica_rate",
-                              engine.rel_speed * engine.capacity,
-                              replica=str(r))
         return []
 
     # ------------------------------------------------------------------ run

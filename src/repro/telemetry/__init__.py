@@ -7,7 +7,8 @@ stream, in-memory ring, Chrome-trace/Perfetto export). Instrumentation
 sites across session / orchestrator / serve / cluster / kernels call
 ``telemetry.get()`` — the active recorder, or the no-op `NULL` recorder
 when telemetry is off, which keeps the off path bit-identical to
-uninstrumented code.
+uninstrumented code. Every span, on or off, is also a
+``jax.profiler.TraceAnnotation``: program spans appear in profiler traces.
 
 Typical wiring (the launchers' ``--telemetry out.jsonl``)::
 

@@ -7,7 +7,7 @@ Three primitives, one event stream:
   every increment is emitted with the running ``total`` so a JSONL stream
   can be cut at any point and still read absolutely.
 * **gauge** — a sampled value per (name, labels) series (goodput,
-  rel_iter_time, power boost, per-replica rates).
+  rel_iter_time, transition bytes).
 * **hist** — raw observations (TTFT, TPOT, plan latencies); aggregation
   (count/mean/p50/p99) happens at read time (`summarize`), never at record
   time, so the stream stays lossless.
@@ -28,11 +28,17 @@ Timestamps are seconds on ``time.perf_counter`` relative to the recorder's
 creation (monotonic — wall-clock jumps never corrupt durations); the clock
 is injectable for deterministic tests.
 
-The **off path is the null recorder** (`NULL`): every method is a no-op and
-``enabled`` is False, so instrumented code guarded by ``telemetry.get()``
-adds a dict lookup and a no-op call — nothing else. Recorder-off behavior
-is bit-identical to uninstrumented code by construction (no device syncs,
-no numerics anywhere in this module).
+Every span, recorded or not, is also a ``jax.profiler.TraceAnnotation`` of
+the same name with its labels as arguments, so program spans land on the
+host plane of any ``jax.profiler`` trace, on the device ops' clock. The
+annotation encodes its labels only while a trace is active; ``jax`` is
+imported at the first span.
+
+The **off path is the null recorder** (`NULL`): ``enabled`` is False,
+counters, gauges and histograms are no-ops, and a span is the bare trace
+annotation, recording nothing. Recorder-off behavior is bit-identical to
+uninstrumented code by construction (no device syncs, no numerics anywhere
+in this module).
 """
 from __future__ import annotations
 
@@ -54,16 +60,29 @@ def _series_key(name: str, labels: Dict) -> Tuple:
     return (name,) + tuple(sorted(labels.items()))
 
 
+_TraceAnnotation = None
+
+
+def _annotation(name: str, labels: Dict):
+    """A ``jax.profiler.TraceAnnotation`` for one span (jax imported at the
+    first call)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name, **labels)
+
+
 class Span:
     """One timed region. Created by `Recorder.span`; emits its event on
     ``__exit__``. ``set(**attrs)`` attaches attributes (e.g. the transition
     ledger's byte counts), ``mark(phase)`` records the phase's offset from
     span start into ``attrs["marks"]``."""
 
-    __slots__ = ("_rec", "name", "labels", "attrs", "t0", "t1")
+    __slots__ = ("_rec", "_ann", "name", "labels", "attrs", "t0", "t1")
 
     def __init__(self, rec: "Recorder", name: str, labels: Dict):
         self._rec = rec
+        self._ann = _annotation(name, labels)
         self.name = name
         self.labels = labels
         self.attrs: Dict = {}
@@ -80,11 +99,13 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        self._ann.__enter__()
         self.t0 = self._rec._now()
         return self
 
     def __exit__(self, *exc) -> None:
         self.t1 = self._rec._now()
+        self._ann.__exit__(*exc)
         self._rec._emit({
             "t0": round(self.t0, 9), "t1": round(self.t1, 9),
             "dur": round(self.t1 - self.t0, 9),
@@ -94,10 +115,13 @@ class Span:
 
 
 class _NullSpan:
-    """Reusable no-op span (the off path). Stateless, so one singleton
-    serves every ``with`` block."""
+    """The off path's span: the trace annotation alone; ``set`` and
+    ``mark`` record nothing."""
 
-    __slots__ = ()
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, labels: Dict):
+        self._ann = _annotation(name, labels)
 
     def set(self, **attrs) -> "_NullSpan":
         return self
@@ -106,13 +130,11 @@ class _NullSpan:
         return self
 
     def __enter__(self) -> "_NullSpan":
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
-        return None
-
-
-_NULL_SPAN = _NullSpan()
+        self._ann.__exit__(*exc)
 
 
 class Recorder:
@@ -201,9 +223,10 @@ class Recorder:
 
 
 class NullRecorder:
-    """The off path: same surface as `Recorder`, every method a no-op.
-    ``telemetry.get()`` returns the singleton `NULL` unless a recorder was
-    configured, so uninstrumented behavior is preserved exactly."""
+    """The off path: same surface as `Recorder`, every method a no-op but
+    `span`, which only annotates the profiler's trace. ``telemetry.get()``
+    returns the singleton `NULL` unless a recorder was configured, so
+    uninstrumented behavior is preserved exactly."""
 
     enabled = False
     sinks: List = []
@@ -219,7 +242,7 @@ class NullRecorder:
         return None
 
     def span(self, name: str, **labels) -> _NullSpan:
-        return _NULL_SPAN
+        return _NullSpan(name, labels)
 
     def total(self, name: str, **labels) -> float:
         return 0

@@ -31,6 +31,7 @@ from repro.sharding.specs import zero1_shardings
 # ---------------------------------------------------------------------------
 # loss
 
+@jax.named_scope("loss_head")
 def cross_entropy(logits, targets, real_vocab: int):
     """Mean next-token CE over (B,S). Handles Megatron vocab padding by
     masking padded logits; fp32 reductions."""
@@ -211,10 +212,11 @@ def make_setup(
             (total, ce), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch
             )
-            lr_scale = lr_schedule(opt_state["step"])
-            params, opt_state, metrics = adamw_update(
-                grads, opt_state, params, opt_cfg, lr_scale
-            )
+            with jax.named_scope("optimizer"):
+                lr_scale = lr_schedule(opt_state["step"])
+                params, opt_state, metrics = adamw_update(
+                    grads, opt_state, params, opt_cfg, lr_scale
+                )
             metrics.update(loss=ce, total_loss=total)
             return params, opt_state, metrics
 
@@ -239,10 +241,11 @@ def make_setup(
                     jnp.add, grads, g
                 )
             grads = jax.tree.map(lambda x: x / m, grads)
-            lr_scale = lr_schedule(opt_state["step"])
-            params, opt_state, metrics = adamw_update(
-                grads, opt_state, params, opt_cfg, lr_scale
-            )
+            with jax.named_scope("optimizer"):
+                lr_scale = lr_schedule(opt_state["step"])
+                params, opt_state, metrics = adamw_update(
+                    grads, opt_state, params, opt_cfg, lr_scale
+                )
             metrics.update(loss=ce / m, total_loss=total / m,
                            microbatches=jnp.int32(m))
             return params, opt_state, metrics

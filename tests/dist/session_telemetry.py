@@ -107,7 +107,10 @@ assert len(executed) == len(ledger) == 4, (len(executed), len(ledger))
 for sp, want in zip(executed, ledger):
     assert sp["attrs"]["bytes_moved"] == want["bytes_moved"], (sp, want)
     assert sp["attrs"]["messages"] == want["messages"], (sp, want)
-    assert sp["attrs"]["marks"]["planned"] <= sp["attrs"]["marks"]["executed"]
+    marks = sp["attrs"]["marks"]
+    assert list(marks) == ["planned", "gathered", "repacked", "placed",
+                           "executed"], marks
+    assert sorted(marks.values()) == list(marks.values()), marks
 assert [e["labels"]["kind"] for e in executed] == \
     ["failure", "failure", "repair", "repair"]
 # the session's executed-bytes gauge mirrors the span series
@@ -126,6 +129,14 @@ assert len(oev) == 4 and all(e["attrs"]["outcome"] == "applied" for e in oev)
 steps = [e for e in events if e["kind"] == "span"
          and e["name"] == "session.step"]
 assert len(steps) == STEPS, len(steps)
+# the first step, and the first after each executed transition, pays for
+# the step's trace and compile and says so
+compiled = [e for e in steps if e["attrs"].get("compiled")]
+assert compiled[0] is steps[0], steps[0]
+for sp in executed:
+    after = next(e for e in steps if e["t0"] >= sp["t1"])
+    assert after["attrs"].get("compiled") is True, after
+assert len(compiled) == 1 + len(executed), len(compiled)
 rel = [e["value"] for e in events if e["kind"] == "gauge"
        and e["name"] == "train.rel_iter_time"
        and e["labels"].get("source") == "analytic"]

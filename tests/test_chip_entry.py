@@ -44,6 +44,27 @@ def test_env_cache_dir_is_the_only_one(tmp_path):
     assert any(tmp_path.iterdir())
 
 
+def test_cached_program_keeps_its_own_op_names(tmp_path):
+    """Two programs that differ only in a named scope are two cache
+    entries: the second compiles and its ops carry its own scope, not the
+    cached program's."""
+    r = _run(["-c", (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import enable_compile_cache\n"
+        "enable_compile_cache()\n"
+        "def f(scope):\n"
+        "    def g(x):\n"
+        "        with jax.named_scope(scope):\n"
+        "            return jnp.sin(x) * 2\n"
+        "    return jax.jit(g).lower(jnp.ones(8)).compile().as_text()\n"
+        "print('/old/' in f('old'), '/new/' in f('new'))\n")],
+        JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split() == ["True", "True"]
+    assert len(list(tmp_path.iterdir())) >= 2
+
+
 def test_default_cache_dir_is_fixed_and_ignored(monkeypatch):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     was = jax.config.jax_compilation_cache_dir

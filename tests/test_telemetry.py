@@ -8,8 +8,10 @@ Host-side, no mesh, deterministic via an injected clock. What's pinned:
 * counter totals per labeled series, span marks/attrs, and span emission
   on ``__exit__`` even when an exception propagates (transition spans must
   survive a `DeadReplicaError` raised mid-apply);
-* the NULL recorder off path: ``enabled`` False, zero allocation (one
-  reusable span singleton), every method a no-op;
+* the NULL recorder off path: ``enabled`` False, every method a no-op
+  but ``span``, which is only a profiler trace annotation;
+* every span, recorder on or off, lands in a ``jax.profiler`` trace under
+  its own name, with its labels, while the JSONL event keeps its schema;
 * scoped activation (`recording`) restore, exception-safe;
 * `JsonlSink` lazy open + `load_jsonl` round-trip (+ corrupt-line error
   with a line number), `MemorySink` ring bounds and label-subset queries;
@@ -121,12 +123,39 @@ def test_null_recorder_is_inert():
     assert NULL.gauge("x", 1.0) is None
     assert NULL.hist("x", 1.0) is None
     assert NULL.total("x") == 0
-    # one reusable singleton span: no per-call allocation on the off path
-    s1, s2 = NULL.span("a"), NULL.span("b", k="v")
-    assert s1 is s2
-    with NULL.span("x") as sp:
+    # the off-path span records nothing: set/mark return the span itself,
+    # and no sink exists to receive an event
+    with NULL.span("x", k="v") as sp:
         assert sp.set(a=1) is sp
         assert sp.mark("p") is sp
+    assert NULL.sinks == [] and not hasattr(sp, "attrs")
+
+
+def test_spans_annotate_the_profiler_trace(tmp_path):
+    """Spans reach the profiler's host plane, on the device ops' clock,
+    with the recorder off (the NULL span) and on; the recorded JSONL event
+    is unchanged by the annotation."""
+    import jax
+    from jax.profiler import ProfileData
+
+    rec, sink, _ = make_rec()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with NULL.span("test.off", k="v") as sp:
+            sp.set(a=1)
+        with rec.span("test.on", n=3):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    host = [pl for pl in ProfileData.from_file(str(path)).planes
+            if pl.name == "/host:CPU"]
+    seen = {ev.name: dict(ev.stats) for pl in host for ln in pl.lines
+            for ev in ln.events if ev.name.startswith("test.")}
+    assert seen == {"test.off": {"k": "v"}, "test.on": {"n": 3}}
+    (ev,) = sink.spans("test.on")
+    assert tuple(ev) == EVENT_KEYS["span"]
+    assert ev["labels"] == {"n": 3} and ev["attrs"] == {}
 
 
 def test_get_defaults_to_null_and_recording_restores():
