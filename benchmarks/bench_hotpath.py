@@ -259,7 +259,7 @@ def _worker_recorded(smoke, rec, np, jax, jnp, pm, ops, make_staged_mesh,
     idx = jnp.asarray(krng.integers(0, 9, size=(4, 3)), jnp.int32)
     calls = {
         "flash_attention": lambda i: ops.flash_attention(
-            q, k, k, block_q=64, block_k=64, interpret=i),
+            q, k, k, interpret=i),
         "rmsnorm": lambda i: ops.rmsnorm(xr, wr, block_rows=64, interpret=i),
         "ssd_scan": lambda i: ops.ssd_scan(xs, dts, As, Bs, Bs, chunk=32,
                                            interpret=i),

@@ -1,92 +1,66 @@
-"""Flash attention forward — Pallas TPU kernel.
+"""Flash attention with a backward pass — Pallas TPU kernels.
 
-Blockwise softmax-attention with causal / sliding-window / chunked-local
-masks and gemma2-style logit softcap: the compute hot spot of every
-attention arch in the assigned pool. Tiling:
+Built on JAX's splash attention kernels
+(`jax.experimental.pallas.ops.tpu.splash_attention`): a forward kernel and
+a fused backward kernel (dk, dv and per-kv-block dq partials, summed in
+f32) under one ``custom_vjp``, with
 
-  grid = (batch·q_heads, S/bq, S/bk);  the k axis is the innermost
-  (sequential) dim, carrying running (m, l, acc) in VMEM scratch.
+  - the running max, the denominator and the output accumulator in VMEM
+    scratch (f32), and the logsumexp saved for the backward (f32);
+  - block-sparse masks: a (q block, kv block) tile that the mask empties is
+    neither loaded nor multiplied (causal, sliding-window and chunked masks
+    skip every tile wholly above the diagonal or outside the window);
+  - GQA: each KV head is index-mapped to its ``H // KVH`` query heads, never
+    repeated in HBM.
 
-  q tile   (1, 1, bq, d)   VMEM
-  k,v tile (1, 1, bk, d)   VMEM — index-mapped h -> h // q_per_kv, so GQA
-                           never materializes repeated KV heads.
-  out tile (1, 1, bq, d)   VMEM, written on the last k step.
+Precision is the caller's dtype. With f32 q/k/v every MXU dot runs at the
+default precision, which on the TPU rounds each operand (q, k, v, p, dO,
+dS) to bfloat16 once and accumulates in f32 — the rounding an XLA
+default-precision f32 einsum makes; the softmax statistics, accumulators,
+the output and dq/dk/dv stay f32.
 
-bq/bk default 512/512 (multiples of the 128 MXU tile; ~(512·128 + 2·512·128
-+ 512·512)·4B ≈ 1.6 MB of VMEM live per step at d=128).
-
-Validated in interpret mode against ref.flash_attention_ref across
-shapes/dtypes/mask kinds (tests/test_kernels.py).
+Tiling: `block_sizes(s, d)` fixes the tiles of both kernels from the
+sequence length and head width (TPU v5e sweep, PERF.md section 6).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_mask as mask_lib,
+)
 
 from repro.kernels.mode import pallas_interpret
 
-NEG_INF = -1e30
+KINDS = ("causal", "sliding", "chunked", "bidir")
 
 
-def _mask(kind: str, q_pos, k_pos, window: int, chunk: int):
-    q = q_pos[:, None]
-    k = k_pos[None, :]
-    ok = k <= q
-    if kind == "sliding":
-        ok &= k > q - window
-    elif kind == "chunked":
-        ok &= (k // chunk) == (q // chunk)
-    elif kind == "bidir":
-        ok = jnp.ones_like(ok)
-    return ok
+def block_sizes(s: int, d: int) -> Optional[tuple[int, int]]:
+    """(forward tile, backward tile) for sequence length ``s`` and head
+    width ``d``, square q x kv tiles; None where 512 does not divide ``s``.
+    From TPU v5e sweeps at hd 64 (PERF.md section 6): 1024-tiles are
+    the fastest forward from S 1024 up. The fused backward alone ran
+    faster on 1024-tiles, but the training step did not, so it keeps 512.
+    Both compile at hd 64 and 128."""
+    del d
+    if s % 512:
+        return None
+    return (1024 if s % 1024 == 0 else 512), 512
 
 
-def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, kind: str, window: int, chunk: int, softcap: Optional[float],
-    scale: float, bq: int, bk: int, nk: int,
-):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32) * scale        # (bq, d)
-    k = k_ref[0, 0].astype(jnp.float32)                # (bk, d)
-    v = v_ref[0, 0].astype(jnp.float32)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                   # (bq, bk)
-    if softcap is not None:
-        s = softcap * jnp.tanh(s / softcap)
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq,), 0)
-    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bk,), 0)
-    s = jnp.where(_mask(kind, q_pos, k_pos, window, chunk), s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
-
-    @pl.when(ki == nk - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+def _mask(kind: str, s: int, window: int, chunk: int):
+    shape = (s, s)
+    if kind == "causal":
+        return splash.CausalMask(shape)
+    if kind == "sliding":  # k in (q - window, q]
+        return splash.LocalMask(shape, window_size=(window - 1, 0), offset=0)
+    if kind == "chunked":
+        return mask_lib.ChunkedCausalMask(shape, chunk_size=chunk)
+    if kind == "bidir":
+        return splash.FullMask(shape)
+    raise ValueError(f"flash_attention: unknown kind={kind!r}; one of {KINDS}")
 
 
 def flash_attention(
@@ -95,60 +69,43 @@ def flash_attention(
     window: int = 4096,
     chunk: int = 8192,
     softcap: Optional[float] = None,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool | None = None,
 ):
     """q: (B, H, S, D); k/v: (B, KVH, S, D) with H % KVH == 0.
-    Returns (B, H, S, D) in q.dtype.
+    Returns softmax(q k^T / sqrt(D)) v as (B, H, S, D) in q.dtype;
+    differentiable in q, k and v.
 
-    ``interpret=None`` resolves via `kernels.mode.pallas_interpret`
-    (compiled on TPU/GPU, interpret on CPU)."""
+    Tiles default to `block_sizes(S, D)`, else to the whole sequence;
+    ``block_q``/``block_k`` set both kernels' tiles.
+    ``interpret=None`` resolves via
+    `kernels.mode.pallas_interpret` (compiled on TPU, interpret on CPU)."""
     b, h, s, d = q.shape
-    kvh = k.shape[1]
-    qpk = h // kvh
-    bq = min(block_q, s)
-    bk = min(block_k, s)
-    if s % bq != 0:
-        raise ValueError(
-            f"flash_attention: sequence length s={s} is not divisible by the "
-            f"query-block size block_q={bq}; pad the sequence or pass a "
-            f"block_q that divides {s}"
-        )
-    if s % bk != 0:
-        raise ValueError(
-            f"flash_attention: sequence length s={s} is not divisible by the "
-            f"key-block size block_k={bk}; pad the sequence or pass a "
-            f"block_k that divides {s}"
-        )
-    nq, nk = s // bq, s // bk
+    fwd, bwd = block_sizes(s, d) or (s, s)
+    bq = fwd if block_q is None else min(block_q, s)
+    bk = fwd if block_k is None else min(block_k, s)
+    bq_bwd = bwd if block_q is None else bq
+    bk_bwd = bwd if block_k is None else bk
+    for size, what, arg in ((bq, "query-block", "block_q"),
+                            (bk, "key-block", "block_k")):
+        if s % size:
+            raise ValueError(
+                f"flash_attention: sequence length s={s} is not divisible by "
+                f"the {what} size {arg}={size}; pad the sequence or pass a "
+                f"{arg} that divides {s}"
+            )
     interpret = pallas_interpret(interpret)
 
-    kernel = functools.partial(
-        _flash_kernel, kind=kind, window=window, chunk=chunk,
-        softcap=softcap, scale=d ** -0.5, bq=bq, bk=bk, nk=nk,
-    )
-    grid = (b * h, nq, nk)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bh, qi, ki: (bh // h, bh % h, qi, 0)),
-            pl.BlockSpec(
-                (1, 1, bk, d), lambda bh, qi, ki: (bh // h, (bh % h) // qpk, ki, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, bk, d), lambda bh, qi, ki: (bh // h, (bh % h) // qpk, ki, 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, bq, d), lambda bh, qi, ki: (bh // h, bh % h, qi, 0)
+    mask = splash.MultiHeadMask([_mask(kind, s, window, chunk)] * h)
+    kernel = splash.make_splash_mha(
+        mask,
+        block_sizes=splash.BlockSizes(
+            block_q=bq, block_kv=bk, block_kv_compute=bk,
+            block_q_dkv=bq_bwd, block_kv_dkv=bk_bwd,
+            block_kv_dkv_compute=bk_bwd, use_fused_bwd_kernel=True,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),    # running max
-            pltpu.VMEM((bq,), jnp.float32),    # running denom
-            pltpu.VMEM((bq, d), jnp.float32),  # running accumulator
-        ],
-        interpret=interpret,
-    )(q, k, v)
+        attn_logits_soft_cap=softcap,
+        head_shards=1, q_seq_shards=1, interpret=interpret,
+    )
+    return jax.vmap(kernel)(q * d ** -0.5, k, v)

@@ -32,7 +32,7 @@ def _flash_attention(q, k, v, *, kind, window, chunk, softcap, block_q,
 
 
 def flash_attention(q, k, v, *, kind="causal", window=4096, chunk=8192,
-                    softcap=None, block_q=512, block_k=512, interpret=None):
+                    softcap=None, block_q=None, block_k=None, interpret=None):
     return _flash_attention(
         q, k, v, kind=kind, window=window, chunk=chunk, softcap=softcap,
         block_q=block_q, block_k=block_k,

@@ -1,6 +1,8 @@
 """Self/cross attention with GQA, sliding-window / chunked-local masks,
-logit softcap, qk-norm, RoPE, KV-cache decode, and a memory-safe blockwise
-("flash", pure-jnp double-scan) path for long sequences.
+logit softcap, qk-norm, RoPE and KV-cache decode. The softmax core is the
+Pallas flash kernel on the TPU where `xla_reason` admits the call, else
+naive, or a memory-safe blockwise ("flash", pure-jnp double-scan) path for
+long sequences.
 
 Megatron-TP layout (paper §3.1 "Attention blocks"): W_Q/W_O partitioned on the
 head dimension over the `model` axis; W_K/W_V replicated whenever
@@ -9,28 +11,33 @@ needs, exactly Megatron's GQA behaviour.
 """
 from __future__ import annotations
 
+import os as _os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import telemetry
 from repro.configs.base import ArchConfig
+from repro.kernels.flash_attention import block_sizes, flash_attention
 from repro.models.common import ShardCtx, apply_rope, dense_init, rms_norm, softcap
 
 NEG_INF = -2.0e38  # fp32-safe mask value
 
-# §Perf iteration C1 (REFUTED, kept for the record): switching train_4k to
-# the blockwise-jnp path cost +18% HBM-proxy traffic vs naive — the fp32
-# tile pipeline materializes at fusion boundaries; blockwise only wins when
-# tiles stay in VMEM, i.e. via kernels/flash_attention.py on real TPUs.
-# Threshold stays 8192: naive ≤8k (where S² scores fit), blockwise above
-# (where they cannot). REPRO_FLASH_MIN_SEQ overrides for experiments.
-import os as _os
-
-FLASH_SEQ_THRESHOLD = int(_os.environ.get("REPRO_FLASH_MIN_SEQ", "8192"))
+# Off the TPU, a self-attention core longer than FLASH_SEQ_THRESHOLD runs
+# blockwise in jnp (`_attend_flash`, O(block²) live memory), shorter ones
+# naive (S² scores). On the TPU the Pallas flash kernel
+# (kernels/flash_attention.py) takes every call that `xla_reason` admits:
+# it keeps its tiles in VMEM and skips masked blocks. In a chip sweep of
+# the core alone (TPU v5e, PERF.md section 6) it beat the naive path at
+# every length swept, 512 to 4096, so it takes every length its tiles
+# divide (`block_sizes`: multiples of 512).
+FLASH_SEQ_THRESHOLD = 8192
 FLASH_BLOCK_Q = 512
 FLASH_BLOCK_K = 1024
+KERNEL_MASKS = {"attn": "causal", "attn_sw": "sliding",
+                "attn_chunked": "chunked", "attn_bidir": "bidir"}
 
 
 # ---------------------------------------------------------------------------
@@ -166,6 +173,32 @@ def _attend_flash(q, k, v, q_pos, k_pos, kind, window, chunk, cap):
 
 
 # ---------------------------------------------------------------------------
+# dispatch
+
+def xla_reason(kind: str, s: int, hd: int, *, cross: bool, cached: bool,
+               ctx: ShardCtx, backend: str) -> Optional[str]:
+    """Why a call's softmax core stays on the XLA path, or None when the
+    Pallas flash kernel takes it. The kernel takes self-attention without a
+    cache (training and full forward passes; its masks are by index, and
+    such a call's positions are 0..S-1) on the TPU, under no mesh or one
+    whose TP axis has size 1 (a head-sharded custom call would be
+    all-gathered around), for a mask it has, on whole blocks."""
+    if cross:
+        return "cross"
+    if cached:
+        return "cache"
+    if backend != "tpu":
+        return "backend"
+    if ctx.mesh is not None and dict(ctx.mesh.shape).get(ctx.tp, 1) > 1:
+        return "tp_sharded"
+    if kind not in KERNEL_MASKS:
+        return "kind"
+    if block_sizes(s, hd) is None:
+        return "seq_len"
+    return None
+
+
+# ---------------------------------------------------------------------------
 # public apply
 
 @jax.named_scope("attention")
@@ -278,9 +311,29 @@ def attn_apply(
             else:
                 new_cache = cross_cache
 
+    reason = xla_reason(kind, s, hd, cross=cross, cached=cache is not None,
+                        ctx=ctx, backend=jax.default_backend())
+    tel = telemetry.get()
+    if tel.enabled:
+        if reason is None:
+            tel.counter("attention.path", path="kernel")
+        else:
+            tel.counter("attention.path", path="xla", reason=reason)
+
     qg = _group(q, kvh)  # (B,kvh,g,S,hd)
 
-    if cross:
+    if reason is None:
+        # f32 operands: the kernel's dots round them to bf16 at the default
+        # precision as the XLA einsums below do; its output is f32
+        f32 = jnp.float32
+        out = flash_attention(
+            q.transpose(0, 2, 1, 3).astype(f32),
+            k.transpose(0, 2, 1, 3).astype(f32),
+            v.transpose(0, 2, 1, 3).astype(f32),
+            kind=KERNEL_MASKS[kind], window=cfg.window, chunk=cfg.chunk_size,
+            softcap=cfg.attn_softcap,
+        ).reshape(qg.shape)
+    elif cross:
         bias = jnp.zeros((1, 1, 1, s, k.shape[1]), jnp.float32)
         out = _attend_naive(qg, k, v, bias, cfg.attn_softcap)
     elif s > FLASH_SEQ_THRESHOLD:

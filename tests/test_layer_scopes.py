@@ -58,3 +58,27 @@ def test_ntp_train_step_names_its_layers():
     batch = jnp.asarray(np.zeros((2, 9)), jnp.int32)
     text = step.lower(params, opt.init(params), batch).compile().as_text()
     assert TRAIN_SCOPES | {"embed", "norm"} <= scope_components(text)
+
+
+def test_arch_train_step_calls_the_attention_kernel_in_its_scope(monkeypatch):
+    """On the TPU (the backend steered here; lowered for the TPU on the
+    CPU) each computation that holds a flash-kernel call is called from the
+    `attention` scope: the forward, its recomputation and the backward.
+    The compiled step's whole op_names, and which of them read as
+    backward, are checked at granite's widths in test_tpu_compile.py."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = reduced_cfg("granite-3-2b")
+    su = make_setup(cfg, ShapeSpec("t", 1024, 2, "train"), None,
+                    param_dtype=jnp.float32)
+    hlo = su.jit_step().trace(*su.abstract_args()).lower(
+        lowering_platforms=("tpu",)).as_text(dialect="hlo", debug_info=True)
+    heads = list(re.finditer(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\s*$", hlo,
+                             re.M))
+    holders = {m.group(1) for m, nxt in zip(heads, heads[1:] + [None])
+               if "tpu_custom_call" in hlo[m.end():nxt and nxt.start()]}
+    sites = [name for callee, name in re.findall(
+        r'to_apply=%?([\w.\-]+)[^\n]*?op_name="([^"]*)"', hlo)
+        if callee in holders]
+    assert len(holders) == 3 and len(sites) == 3, (holders, sites)
+    assert all("attention" in scope_components(f'op_name="{n}"')
+               for n in sites), sites

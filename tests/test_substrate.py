@@ -198,6 +198,78 @@ def test_attention_flash_path_matches_naive():
 
 
 # ---------------------------------------------------------------------------
+# which core attn_apply takes: the Pallas flash kernel only on the TPU, with
+# no head-sharding mesh, for cache-free self-attention on whole blocks
+
+@pytest.mark.parametrize("change,reason", [
+    ({}, None),
+    ({"tp": 1}, None),
+    ({"kind": "attn_sw"}, None),
+    ({"kind": "attn_bidir", "s": 8192}, None),
+    ({"backend": "cpu"}, "backend"),
+    ({"tp": 2}, "tp_sharded"),
+    ({"cross": True}, "cross"),
+    ({"cached": True}, "cache"),
+    ({"kind": "attn_local"}, "kind"),
+    ({"s": 256}, "seq_len"),
+    ({"s": 512}, None),
+    ({"s": 1536}, None),
+    ({"s": 640}, "seq_len"),
+])
+def test_attention_dispatch_predicate(change, reason):
+    from jax.sharding import AbstractMesh
+
+    from repro.models import attention as am
+    from repro.models.common import ShardCtx
+
+    kw = dict(kind="attn", s=4096, hd=64, cross=False, cached=False,
+              tp=None, backend="tpu")
+    kw.update(change)
+    tp = kw.pop("tp")
+    mesh = None if tp is None else AbstractMesh((1, tp), ("data", "model"))
+    kind, s, hd = kw.pop("kind"), kw.pop("s"), kw.pop("hd")
+    assert am.xla_reason(kind, s, hd, ctx=ShardCtx(mesh=mesh), **kw) == reason
+
+
+def _attention_paths(cfg, s, ctx):
+    from repro import telemetry
+    from repro.models import attention as am
+
+    p = am.attn_init(cfg, jax.random.PRNGKey(0), jnp.float32)
+    x = jax.ShapeDtypeStruct((2, s, cfg.d_model), jnp.float32)
+    sink = telemetry.MemorySink()
+    with telemetry.recording(telemetry.Recorder(sinks=[sink])):
+        jax.eval_shape(
+            lambda x: am.attn_apply(cfg, p, x, kind="attn", ctx=ctx)[0], x)
+    return {(e["labels"]["path"], e["labels"].get("reason"))
+            for e in sink.events(name="attention.path")}
+
+
+def test_attention_records_xla_path_on_cpu():
+    from repro.configs import get_arch, reduced
+    from repro.models.common import NO_SHARD
+
+    cfg = reduced(get_arch("granite-3-2b"))
+    assert _attention_paths(cfg, 1024, NO_SHARD) == {("xla", "backend")}
+
+
+@pytest.mark.parametrize("tp,path", [(2, ("xla", "tp_sharded")),
+                                     (1, ("kernel", None))])
+def test_attention_records_path_under_a_tp_mesh(monkeypatch, tp, path):
+    """As on the TPU (the backend steered here): a head-sharded call stays
+    on XLA, a mesh whose TP axis has size 1 takes the kernel."""
+    from jax.sharding import AbstractMesh
+
+    from repro.configs import get_arch, reduced
+    from repro.models.common import ShardCtx
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = reduced(get_arch("granite-3-2b"))
+    ctx = ShardCtx(mesh=AbstractMesh((1, tp), ("data", "model")))
+    assert _attention_paths(cfg, 1024, ctx) == {path}
+
+
+# ---------------------------------------------------------------------------
 # HLO analyzer
 
 def test_hlo_analyzer_exact_matmul():

@@ -9,8 +9,9 @@ inside a fixture (never at import), so a test worker that cannot load the
 TPU library skips these tests instead of breaking collection.
 
 Widths: granite-3-2b (d_model 2048, 32H/8KV, head_dim 64, d_ff 8192) at
-seq 2048; qwen2-7b attention (28H/4KV, head_dim 128) at seq 4096;
-mamba2-780m's SSD (48 heads x 64, d_state 128, chunk 256).
+seq 2048 and, for attention and its train step, 4096; qwen2-7b attention
+(28H/4KV, head_dim 128) at seq 4096; mamba2-780m's SSD (48 heads x 64,
+d_state 128, chunk 256).
 """
 import os
 
@@ -66,13 +67,54 @@ def _compile(one_chip, fn, *shapes):
 F32, BF16, I32 = jnp.float32, jnp.bfloat16, jnp.int32
 
 
-@pytest.mark.parametrize("h,kvh,s,d", [(32, 8, 2048, 64), (28, 4, 4096, 128)])
+@pytest.mark.parametrize("h,kvh,s,d", [(32, 8, 4096, 64), (28, 4, 4096, 128)])
 def test_flash_attention_compiles(one_chip, no_cache, h, kvh, s, d):
-    _compile(one_chip,
-             lambda q, k, v: flash_attention.flash_attention(
-                 q, k, v, kind="causal", interpret=False),
-             ((1, h, s, d), BF16), ((1, kvh, s, d), BF16),
-             ((1, kvh, s, d), BF16))
+    """Forward, and forward with the fused backward, f32 operands as the
+    model passes them."""
+    def fwd(q, k, v):
+        return flash_attention.flash_attention(q, k, v, interpret=False)
+
+    shapes = (((2, h, s, d), F32), ((2, kvh, s, d), F32),
+              ((2, kvh, s, d), F32))
+    _compile(one_chip, fwd, *shapes)
+    _compile(one_chip, lambda q, k, v: jax.grad(
+        lambda *a: fwd(*a).sum(), argnums=(0, 1, 2))(q, k, v), *shapes)
+
+
+def test_granite_train_step_runs_attention_in_the_kernel(
+        one_chip, no_cache, monkeypatch):
+    """granite-3-2b's train step at 1 layer, 2 x 4096 tokens: on the TPU
+    (steered here; the compile runs on the CPU) attention's forward, its
+    recomputed forward and its fused backward are three kernel calls in
+    the `attention` scope, and no f32 S x S array is left in the step's
+    scratch (one is 2 x 32 x 4096^2 x 4 B = 4.29 GB; the XLA path held
+    9.52 GB of scratch here)."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_arch
+    from repro.configs.shapes import ShapeSpec
+    from repro.train.steps import make_setup
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_arch("granite-3-2b"), n_layers=1)
+    b, s = 2, 4096
+    su = make_setup(cfg, ShapeSpec("t", s, b, "train"), None,
+                    param_dtype=F32)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        su.abstract_args())
+    compiled = su.jit_step().lower(*args).compile()
+    text = compiled.as_text()
+    names = [re.search(r'op_name="([^"]*)"', text[m.end():]).group(1)
+             for m in re.finditer(r'custom_call_target="tpu_custom_call"',
+                                  text)]
+    assert len(names) == 3, names
+    assert all("/attention/" in n for n in names), names
+    # the backward kernels, and the recomputed forward, read as backward
+    assert sum("transpose(" not in n for n in names) == 1, names
+    scores = b * cfg.n_heads * s * s * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < scores
 
 
 @pytest.mark.parametrize("d", [2048, 3584])
