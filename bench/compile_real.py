@@ -1,6 +1,8 @@
-"""Compile each training cell's step at its real size for a described TPU
-v5e, without the chip, and print the bytes the program needs on the device
-(``compiled.memory_analysis()``).
+"""Compile each cell's programs at their real size for a described TPU v5e,
+without the chip, and print the bytes each needs on the device
+(``compiled.memory_analysis()``): a training cell's step, a serving cell's
+slot decode and prefill, with the bytes a serving engine holds between them
+(weights and the slot cache).
 
     JAX_PLATFORMS=cpu python3 bench/compile_real.py [--cell NAME] [--layers N]
         [--seq N]
@@ -64,6 +66,40 @@ def train(cell, dev, layers=None, seq=None):
     return {"train_step": _mem(su.jit_step().lower(*args).compile())}
 
 
+def serve(cell, dev, layers=None):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.transformer import build_model
+
+    s = dict(cell.sizes)
+    if layers:
+        s["num_hidden_layers"] = layers
+    tr, C = cell.traffic, cell.config
+    cfg = C.arch_config(s)
+    model = build_model(cfg, remat=False)
+    f32 = jnp.float32
+    params = _on(jax.eval_shape(
+        lambda k: C.to_program(s, C.init(s, k), cfg.padded_vocab()),
+        jax.random.PRNGKey(0)), dev)
+    slots = tr["slots"]
+    cache = _on(jax.eval_shape(lambda: model.init_slot_cache(
+        slots, tr["max_len"], f32)), dev)
+    cache1 = _on(jax.eval_shape(lambda: model.init_cache(
+        1, tr["max_len"], f32)), dev)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=dev)
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize
+                           for x in jax.tree.leaves(t))
+    decode = jax.jit(model.decode_slots).lower(
+        params, cache, i32(slots), i32(slots)).compile()
+    prefill = jax.jit(model.prefill).lower(
+        params, i32(1, tr["prefill_len"]), cache1).compile()
+    return {"layers": s["num_hidden_layers"],
+            "weights_gb": nbytes(params) / GB,
+            "slot_cache_gb": nbytes(cache) / GB,
+            "decode_slots": _mem(decode), "prefill": _mem(prefill)}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cell", default=None)
@@ -82,9 +118,13 @@ def main() -> int:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
         one = SingleDeviceSharding(topo.devices[0])
-        if cell.traffic["driver"] != "train_closed_loop":
-            raise SystemExit(f"{name}: only training cells are compiled here")
-        res = train(cell, one, args.layers, args.seq)
+        if cell.traffic["driver"] == "train_closed_loop":
+            res = train(cell, one, args.layers, args.seq)
+        elif cell.traffic["driver"] == "serve_open_loop":
+            res = serve(cell, one, args.layers)
+        else:
+            raise SystemExit(f"{name}: no programs to compile for driver "
+                             f"{cell.traffic['driver']!r}")
         print(json.dumps({"cell": name, "device": topo.devices[0].device_kind,
                           **res}), flush=True)
     return 0

@@ -2,7 +2,10 @@
 and its plain reference.
 
 Sizes come from a configuration file beside this one whose ``model`` is
-``granite-3-2b`` (``granite-3-2b-train.json`` cuts the depth). The
+``granite-3-2b`` (``granite-3-2b-train.json`` and
+``granite-3-2b-serve.json`` cut the depth). ``forward`` is the full
+forward to logits that both the training loss and the serving check
+follow. The
 reference is the Granite block: pre-norm RMSNorm, grouped-query attention
 with half-split RoPE, a SwiGLU MLP, a head tied to the embedding, and the
 muP multipliers on the embedding, attention, residual branches and logits,
@@ -205,3 +208,34 @@ def train_flops_per_token(s, seq: int) -> float:
     forward for QK^T and PV together, times 3). No recomputation counted."""
     d, h, kv, hd, ff, n, v = dims(s)
     return 6.0 * matmul_params(s) + 6.0 * n * seq * h * hd
+
+
+# ------------------------------------------------------------ serving
+
+def serve_token_flops(s, ctx: int, head: bool) -> float:
+    """Forward of one token that attends ``ctx`` positions, itself
+    included: 2 per matmul parameter of every layer, 4*H*hd*ctx per layer
+    for QK^T and PV, and the tied head's 2*d*V only where the token's
+    logits are used."""
+    d, h, kv, hd, ff, n, v = dims(s)
+    return (2.0 * n * layer_matmul_params(s) + 4.0 * n * h * hd * ctx
+            + (2.0 * d * v if head else 0.0))
+
+
+def prefill_flops(s, length: int) -> float:
+    """A causal prefill of ``length`` real tokens (padding not counted),
+    with the head on the last position alone: the one whose logits pick
+    the first served token."""
+    d, h, kv, hd, ff, n, v = dims(s)
+    return (2.0 * n * layer_matmul_params(s) * length
+            + 4.0 * n * h * hd * length * (length + 1) / 2 + 2.0 * d * v)
+
+
+def decode_tick_bytes(s, live_positions: float, itemsize: int = 4) -> float:
+    """Bytes one tick of the slot decode must read: every layer's
+    projections and norms, the final norm, the tied head over the real
+    vocabulary, and the K and V of the live positions (the positions that
+    the active slots attend, summed over them)."""
+    d, h, kv, hd, ff, n, v = dims(s)
+    weights = n * (layer_matmul_params(s) + 2 * d) + d + d * v
+    return itemsize * (weights + live_positions * n * 2 * kv * hd)

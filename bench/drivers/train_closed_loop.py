@@ -98,20 +98,21 @@ def window(run, st):
     pending = deque()
     input_s, steps = [], 0
     i = st["next_step"]
+    pauses = harness.HostPauses()
     with run.traced():
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < run.seconds:
-            with span("bench.batch"):
+            with span("bench.batch"), pauses.part("batch"):
                 tb = time.perf_counter()
                 b = pipe.batch(i)
                 input_s.append(time.perf_counter() - tb)
-            with span("bench.step"):
+            with span("bench.step"), pauses.part("step"):
                 metrics = session.step(b)
             pending.append(metrics["loss"])
             steps += 1
             i += 1
             if len(pending) > tr["in_flight"]:
-                with span("bench.wait"):
+                with span("bench.wait"), pauses.part("wait"):
                     jax.block_until_ready(pending.popleft())
         with span("bench.wait"):
             jax.block_until_ready((session.params, list(pending)))
@@ -119,6 +120,7 @@ def window(run, st):
     tokens = steps * tr["batch"] * tr["seq"]
     return {"steps": steps, "tokens": tokens, "window_s": t1 - t0,
             "t0": t0, "t1": t1, "input_s": input_s,
+            "host": pauses.close(),
             "last_loss": float(pending[-1]) if pending else None}
 
 
@@ -203,6 +205,8 @@ def run(run):
     checks = [Check(k, v, lim[k]) for k, v in nums.items()]
     e2e = {"setup_s": run.setup_s,
            "train_tokens_per_s": win["tokens"] / win["window_s"]}
+    print(f"train window: {win['steps']} steps; "
+          + harness.HostPauses.describe(win["host"]), file=sys.stderr)
     records = {"window": win, "check": {"program": rec, "reference": ref},
                "setup_compile_s": run.setup_compile_s}
     if run.readings:

@@ -19,6 +19,7 @@ import importlib.util
 import json
 import math
 import os
+import resource
 import shutil
 import statistics
 import sys
@@ -188,6 +189,67 @@ def span(name: str, **kw):
     import jax
 
     return jax.profiler.TraceAnnotation(name, **kw)
+
+
+class HostPauses:
+    """The longest host pause of a loop, and what the process did in it.
+    ``part(name)`` times one named part of an iteration on the wall clock;
+    for the longest it keeps the CPU time of the calling thread and of the
+    whole process, and the process's major page faults and involuntary
+    context switches over it. Python's collector pauses are summed beside.
+    A pause that the thread's CPU time nearly fills was work of this
+    process; one with little CPU time waited, on the device or on the
+    machine."""
+
+    def __init__(self):
+        import gc
+
+        self.longest: Optional[dict] = None
+        self.gc_s, self.gc_max_s, self._gc_t = 0.0, 0.0, None
+        gc.callbacks.append(self._gc)
+
+    def _gc(self, phase, info):
+        if phase == "start":
+            self._gc_t = time.perf_counter()
+        elif self._gc_t is not None:
+            d = time.perf_counter() - self._gc_t
+            self.gc_s += d
+            self.gc_max_s = max(self.gc_max_s, d)
+            self._gc_t = None
+
+    @contextlib.contextmanager
+    def part(self, name: str):
+        r0 = resource.getrusage(resource.RUSAGE_SELF)
+        c0, p0, t0 = time.thread_time(), time.process_time(), \
+            time.perf_counter()
+        yield
+        wall = time.perf_counter() - t0
+        if self.longest is None or wall > self.longest["wall_s"]:
+            r1 = resource.getrusage(resource.RUSAGE_SELF)
+            self.longest = {
+                "part": name, "wall_s": wall,
+                "thread_cpu_s": time.thread_time() - c0,
+                "process_cpu_s": time.process_time() - p0,
+                "major_faults": r1.ru_majflt - r0.ru_majflt,
+                "involuntary_switches": r1.ru_nivcsw - r0.ru_nivcsw}
+
+    def close(self) -> dict:
+        import gc
+
+        gc.callbacks.remove(self._gc)
+        return {"longest": self.longest, "gc_s": self.gc_s,
+                "gc_max_s": self.gc_max_s}
+
+    @staticmethod
+    def describe(rec: dict) -> str:
+        x = rec["longest"]
+        if not x:
+            return "host: no parts timed"
+        return (f"host: longest part {x['part']} {x['wall_s']:.3f} s (thread "
+                f"cpu {x['thread_cpu_s']:.3f} s, process cpu "
+                f"{x['process_cpu_s']:.3f} s, {x['major_faults']} major "
+                f"faults, {x['involuntary_switches']} involuntary switches); "
+                f"collector {rec['gc_s']:.3f} s, longest {rec['gc_max_s']:.3f} s")
 
 
 # ---------------------------------------------------------------- checks

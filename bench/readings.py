@@ -1,19 +1,25 @@
 """Read a cell's compared numbers over many seeds in one process: the
 program's (the lower readings of each limit) and, with ``--control``, those
 of the control and of the planted faults against the reference (the upper
-readings). The benchmark's own runs never do this.
+readings). With ``--fault NAME`` every run has that fault of the driver's
+``FAULTS`` planted in the program, and its numbers are upper readings. The
+benchmark's own runs never do this.
 
-    python3 bench/readings.py --workload <name> --seeds 1,2,3 --seconds 5 [--control]
+    python3 bench/readings.py --workload <name> --seeds 1,2,3 --seconds 5 \
+        [--control] [--fault NAME]
 
 One JSON line per seed, in which each control or fault reading is also
 judged by the harness's ``Check`` against the cell's limits
 (``bench/limits/<cell>.json``, where it exists): ``fails`` names the
 numbers it fails. Then a line with the largest program reading and the
-smallest control and fault readings of each number.
+smallest control and fault readings of each number. A reading whose name
+begins with ``program`` (the program against another reference) is a lower
+reading.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
@@ -31,6 +37,7 @@ def main() -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=5.0)
     ap.add_argument("--control", action="store_true")
+    ap.add_argument("--fault", default=None)
     args = ap.parse_args()
     cell = harness.load_cell(args.workload)
     devices = harness.accelerator_devices(cell.chips)
@@ -41,22 +48,31 @@ def main() -> int:
               if limits_file.is_file() else {})
     inf = float("inf")
     prog, upper = {}, {}
+    planted = (cell.driver.FAULTS[args.fault] if args.fault
+               else contextlib.nullcontext)
     for seed in (int(x) for x in args.seeds.split(",")):
         t0 = time.time()
         run = harness.Run(cell=cell, seed=seed, seconds=args.seconds,
                           trace=False, devices=devices, t_process=t0,
                           meter=meter, limits=_AnyLimit(),
                           readings=args.control)
-        out = cell.driver.run(run)
+        with planted():
+            out = cell.driver.run(run)
         nums = {c.name: c.value for c in out.checks}
-        for k, v in nums.items():
-            prog[k] = max(prog.get(k, 0.0), v)
-        reads = out.records.get("readings") or {}
+        reads = dict(out.records.get("readings") or {})
+        if args.fault:
+            reads[args.fault] = nums
+        else:
+            for k, v in nums.items():
+                prog[k] = max(prog.get(k, 0.0), v)
         fails = {}
         for fault, vals in reads.items():
             for k, v in vals.items():
                 key = f"{fault}.{k}"
-                upper[key] = min(upper.get(key, inf), v)
+                if fault.startswith("program"):
+                    prog[key] = max(prog.get(key, 0.0), v)
+                else:
+                    upper[key] = min(upper.get(key, inf), v)
             fails[fault] = [k for k, v in vals.items() if k in limits
                             and not harness.Check(k, v, limits[k]).ok]
         print(json.dumps({"seed": seed, "program": nums, "readings": reads,

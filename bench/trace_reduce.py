@@ -4,9 +4,11 @@ The traced window is the host span ``bench.window`` that the harness puts
 around it. On each TPU device plane the ``XLA Ops`` line gives the
 operations that ran: busy time is the union of their intervals inside the
 window, idle time the rest. The ``XLA Modules`` line gives each program's
-device time. Idle time on the first device is split over the innermost
-``bench.*`` host span at each moment: what the host was doing while the
-device waited.
+device time and how many of its runs lie in the window. Idle time on
+the first device is split over the innermost ``bench.*`` host span at each
+moment: what the host was doing while the device waited. The summary also
+holds ``trace_scopes.summarize``'s keys: device time by named scope, idle
+time by the program's spans, and the step markers.
 """
 from __future__ import annotations
 
@@ -113,6 +115,7 @@ def reduce(path) -> dict:
     window_s = (hi - lo) * 1e-9
 
     busy_s, op_time, mod_time = [], defaultdict(float), defaultdict(float)
+    mod_calls = defaultdict(float)
     first_busy = None
     for k, pl in enumerate(sorted(devices, key=lambda p: p.name)):
         lines = {ln.name: ln for ln in pl.lines}
@@ -125,6 +128,10 @@ def reduce(path) -> dict:
                 if c:
                     mod_time[module_label(ev.name)] += \
                         (c[0][1] - c[0][0]) * 1e-9 / len(devices)
+                    # a run cut by the window's edge counts by its share
+                    # inside, so that time over runs is one run's time
+                    mod_calls[module_label(ev.name)] += \
+                        (c[0][1] - c[0][0]) / max(b - a, 1) / len(devices)
         mods.sort()
         starts = [m[0] for m in mods]
         op_lines = ([lines["XLA Ops"]] if "XLA Ops" in lines else
@@ -163,12 +170,16 @@ def reduce(path) -> dict:
     busy = sum(busy_s) / len(busy_s)
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:TOP]
     top_gaps = sorted(gaps.items(), key=lambda kv: -kv[1])[:TOP]
+    from bench import trace_scopes
+
     return {
+        **trace_scopes.summarize(path),
         "window_s": window_s,
         "busy_s": busy,
         "idle_share": 1.0 - busy / window_s if window_s > 0 else None,
         "devices": len(devices),
         "modules": dict(mod_time),
+        "module_calls": dict(mod_calls),
         "breakdown": {"device_ops": [[k, v] for k, v in top_ops],
                       "idle_gaps": [[k, v] for k, v in top_gaps]},
     }

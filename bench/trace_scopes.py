@@ -5,7 +5,7 @@
 
 The first form reduces a recorded trace; the second runs a cell traced, as
 ``bench/run.py --trace 1`` does, and reduces its trace. Both print one JSON
-object: ``trace_reduce.reduce``'s summary with this module's keys added,
+object: ``trace_reduce.reduce``'s summary, which holds this module's keys,
 and the per-layer numbers read from them (``layers``).
 
 A device operation's layer is in its event metadata: the ``tf_op`` stat
@@ -288,7 +288,7 @@ def layers(summary: dict) -> dict:
 
 
 def reduce_all(path) -> dict:
-    summary = dict(tr.reduce(path), **summarize(path))
+    summary = tr.reduce(path)
     summary["layers"] = layers(summary)
     return summary
 

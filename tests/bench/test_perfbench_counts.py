@@ -60,3 +60,40 @@ def test_peaks_table_names_v5e_and_refuses_an_unknown_device():
     assert peaks["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         harness.device_peaks("cpu")
+
+
+def test_granite_serve_flops_match_the_compiled_programs():
+    """A prefill of P real tokens and one slot decode at a known position,
+    against the compiled ``Model.prefill`` and ``Model.decode_slots``. The
+    compiler also counts what the benchmark leaves out on purpose: the
+    head over every prefilled position but the last, the masked half of
+    the prefill's attention, and the decode's attention over the cache
+    positions past the live ones."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models.transformer import build_model
+
+    cell = T.tiny_cell("granite-serve-chat")
+    s = T.resized(cell.sizes, SIZES)
+    C = cell.config
+    cfg = C.arch_config(s)
+    assert cfg.padded_vocab() == s["vocab_size"]
+    model = build_model(cfg, remat=False)
+    params = jax.eval_shape(lambda k: C.to_program(s, C.init(s, k),
+                                                   cfg.padded_vocab()),
+                            jax.random.PRNGKey(0))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    P, T_, slots, ctx = 128, 256, 4, 100
+    n, h, hd, d, v = (s["num_hidden_layers"], s["num_attention_heads"],
+                      s["head_dim"], s["hidden_size"], s["vocab_size"])
+    cache1 = jax.eval_shape(lambda: model.init_cache(1, T_, jnp.float32))
+    hlo = _hlo_flops(jax.jit(model.prefill).lower(params, i32(1, P), cache1))
+    _bounds(C.prefill_flops(s, P),
+            2.0 * d * v * (P - 1) + 4.0 * n * h * hd * P * (P - 1) / 2, hlo)
+    cache = jax.eval_shape(lambda: model.init_slot_cache(slots, T_,
+                                                         jnp.float32))
+    hlo = _hlo_flops(jax.jit(model.decode_slots).lower(
+        params, cache, i32(slots), i32(slots)))
+    _bounds(slots * C.serve_token_flops(s, ctx, head=True),
+            slots * 4.0 * n * h * hd * (T_ - ctx), hlo)

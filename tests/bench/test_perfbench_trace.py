@@ -32,6 +32,8 @@ def test_window_busy_and_idle(summary):
 
 def test_programs_and_ops_are_named_stably(summary):
     assert set(summary["modules"]) == {"jit__lambda"}
+    # four runs of the program, the first ended before the window opened
+    assert summary["module_calls"] == {"jit__lambda": pytest.approx(3.0)}
     ops = summary["breakdown"]["device_ops"]
     assert 1 <= len(ops) <= tr.TOP
     assert ops[0][0] == "jit__lambda:fusion f32[]"

@@ -70,13 +70,19 @@ def test_step_markers_and_program_spans_in_the_window(scoped):
 
 
 def test_reduce_keys_are_untouched():
-    """The added keys sit beside ``reduce``'s, which keep their values; on a
-    trace with only the harness's spans the program's idle split is the
-    harness's own."""
+    """``reduce`` holds ``summarize``'s keys, unchanged, beside its own, and
+    ``reduce_all`` adds only ``layers``; on a trace with only the harness's
+    spans the program's idle split is the harness's own."""
     base = tr.reduce(SMALL)
+    added = ts.summarize(SMALL)
+    own = {"window_s", "busy_s", "idle_share", "devices", "modules",
+           "module_calls", "breakdown"}
+    assert not own & set(added)
+    assert set(base) == own | set(added)
+    assert {k: base[k] for k in added} == added
     full = ts.reduce_all(SMALL)
-    assert not set(base) & set(ts.summarize(SMALL))
     assert {k: full[k] for k in base} == base
+    assert set(full) - set(base) == {"layers"}
     assert full["scopes"] == {ts.UNSCOPED: pytest.approx(
         {"forward": 0.0, "backward": 0.0, "other": base["busy_s"]},
         rel=1e-3)}
