@@ -2,13 +2,16 @@
 without the chip, and print the bytes each needs on the device
 (``compiled.memory_analysis()``): a training cell's step, a serving cell's
 slot decode and prefill, with the bytes a serving engine holds between them
-(weights and the slot cache).
+(weights and the slot cache), an NTP cell's step in the healthy and in the
+degraded layout on a described v5e:2x2 (bytes on each device).
 
     JAX_PLATFORMS=cpu python3 bench/compile_real.py [--cell NAME] [--layers N]
-        [--seq N]
+        [--seq N] [--local-batch N]
 
-The options override the cell's depth or sequence, to find the largest
-that fits. Nothing runs; a compile that passes is not a chip run.
+The options override the cell's depth, sequence or (NTP) local batch, to
+find the largest that fits. A cell that ``BENCHMARK.json`` does not list
+yet is read from ``bench/queued/<cell>.json``, which holds its entries.
+Nothing runs; a compile that passes is not a chip run.
 """
 from __future__ import annotations
 
@@ -17,6 +20,7 @@ import json
 import os
 import sys
 from pathlib import Path
+from types import SimpleNamespace
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 ROOT = Path(__file__).resolve().parents[1]
@@ -100,11 +104,82 @@ def serve(cell, dev, layers=None):
             "decode_slots": _mem(decode), "prefill": _mem(prefill)}
 
 
+def ntp(cell, topo, layers=None, seq=None, local_batch=None):
+    """The NTP step of each layout the cell's window runs, on a mesh of the
+    described chips, with its arguments placed as the session places them
+    (unit buffers split over data and model, the rest replicated)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import ntp_train as nt
+    from repro.core.nonuniform import FailurePlan, weight_plan
+    from repro.optim import AdamWConfig, adamw
+
+    s = dict(cell.sizes)
+    if layers:
+        s["num_hidden_layers"] = layers
+    tr = dict(cell.traffic)
+    tr["seq"] = seq or tr["seq"]
+    tr["local_batch"] = local_batch or tr["local_batch"]
+    C, D = cell.config, cell.driver
+    cfg = C.model_config(s)
+    d, n1 = tr["data"], tr["model"]
+    mesh = Mesh(np.array(topo.devices[: d * n1]).reshape(d, n1),
+                ("data", "model"))
+    canonical = jax.eval_shape(lambda k: C.init(s, k), jax.random.PRNGKey(0))
+    opt = adamw(AdamWConfig(lr=s["optimizer"]["lr"]))
+    out = {"layers": s["num_hidden_layers"], "seq": tr["seq"],
+           "local_batch": tr["local_batch"]}
+    for name, tp in zip(("healthy", "degraded"), D.expected_plans(tr)):
+        plan = FailurePlan(n1=n1, replica_tp=tp)
+        bufs = {"attn": weight_plan(cfg.n_kv_groups, plan).buf,
+                "mlp": weight_plan(cfg.k_ff, plan).buf}
+
+        def packed(path, x):
+            key = path[-1].key if hasattr(path[-1], "key") else None
+            if key in nt.UNIT_KEYS:
+                buf = bufs["attn" if key.startswith("w") else "mlp"]
+                return jax.ShapeDtypeStruct(
+                    (d, n1 * buf) + x.shape[1:], x.dtype,
+                    sharding=NamedSharding(mesh, P("data", "model")))
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=NamedSharding(mesh, P()))
+
+        params = jax.tree_util.tree_map_with_path(packed, canonical)
+        state = {"m": params, "v": params, "step": jax.ShapeDtypeStruct(
+            (), jnp.int32, sharding=NamedSharding(mesh, P()))}
+        batch = jax.ShapeDtypeStruct(
+            (d * tr["local_batch"], tr["seq"] + 1), jnp.int32,
+            sharding=NamedSharding(mesh, P("data", None)))
+        step = nt.make_ntp_train_step(cfg, plan, mesh,
+                                      local_batch=tr["local_batch"],
+                                      optimizer=opt)
+        out[name] = {"plan": list(tp),
+                     **_mem(step.lower(params, state, batch).compile())}
+    # the reference's step, on one chip at highest precision
+    dev0 = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    on0 = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=dev0)
+    w = jax.tree.map(lambda x: on0(x.shape, x.dtype), canonical)
+    rows = d * tr["local_batch"]
+    run = SimpleNamespace(cell=SimpleNamespace(config=C, sizes=s,
+                                               traffic=tr))
+    with jax.default_matmul_precision("highest"):
+        ref = D.reference_step(run, jnp.float32).lower(
+            w, {"m": w, "v": w}, on0((rows, tr["seq"] + 1), jnp.int32),
+            on0((rows,), jnp.float32), on0((), jnp.int32)).compile()
+    out["reference"] = _mem(ref)
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cell", default=None)
     ap.add_argument("--layers", type=int, default=None)
     ap.add_argument("--seq", type=int, default=None)
+    ap.add_argument("--local-batch", type=int, default=None)
     args = ap.parse_args()
     import jax
     from jax.experimental import topologies
@@ -114,7 +189,10 @@ def main() -> int:
     spec = harness.load_spec()
     names = [args.cell] if args.cell else [w["name"] for w in spec["workloads"]]
     for name in names:
-        cell = harness.load_cell(name, spec)
+        queued = harness.BENCH / "queued" / f"{name}.json"
+        listed = any(w["name"] == name for w in spec["workloads"])
+        cell = harness.load_cell(name, spec if listed or not queued.is_file()
+                                 else json.loads(queued.read_text()))
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
         one = SingleDeviceSharding(topo.devices[0])
@@ -122,6 +200,8 @@ def main() -> int:
             res = train(cell, one, args.layers, args.seq)
         elif cell.traffic["driver"] == "serve_open_loop":
             res = serve(cell, one, args.layers)
+        elif cell.traffic["driver"] == "ntp_fail_repair":
+            res = ntp(cell, topo, args.layers, args.seq, args.local_batch)
         else:
             raise SystemExit(f"{name}: no programs to compile for driver "
                              f"{cell.traffic['driver']!r}")

@@ -3,7 +3,8 @@ program's (the lower readings of each limit) and, with ``--control``, those
 of the control and of the planted faults against the reference (the upper
 readings). With ``--fault NAME`` every run has that fault of the driver's
 ``FAULTS`` planted in the program, and its numbers are upper readings. The
-benchmark's own runs never do this.
+benchmark's own runs never do this. A driver whose check needs no window
+(``ntp_fail_repair``) runs none at ``--seconds 0``.
 
     python3 bench/readings.py --workload <name> --seeds 1,2,3 --seconds 5 \
         [--control] [--fault NAME]
